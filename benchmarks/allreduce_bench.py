@@ -61,7 +61,6 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-import repro.dist  # noqa: E402  (installs compat shard_map)
 from repro.core import topologies as topo  # noqa: E402
 from repro.core.collectives import (CostModel,  # noqa: E402
                                     allreduce_schedule, _best_root_probe,
@@ -78,6 +77,7 @@ from repro.dist.tree_allreduce import (auto_segments,  # noqa: E402
                                        per_tree_allreduce,
                                        pipelined_tree_allreduce,
                                        resolve_codec, spec_from_schedule)
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 TRAJECTORY = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                           os.pardir, "BENCH_allreduce.json")
@@ -136,7 +136,7 @@ def load_calibration(path: str = TRAJECTORY) -> None:
 
 
 def bench_executors(results: dict, elems: int, iters: int) -> None:
-    mesh = jax.make_mesh((16,), ("data",))
+    mesh = make_mesh((16,), ("data",))
     x = (jnp.arange(16 * elems, dtype=jnp.float32).reshape(16, elems)
          * 1e-4)
     nbytes = elems * 4

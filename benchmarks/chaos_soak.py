@@ -82,6 +82,7 @@ from repro.dist.recovery import (RecoveryController,  # noqa: E402
                                  RecoveryPolicy)
 from repro.dist.steps import (dp_size, fault_runtime_for_mesh,  # noqa: E402
                               make_train_step)
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.optim import AdamW, ShardedAdamW, cosine_schedule  # noqa: E402
 from repro.telemetry import metrics as tmetrics  # noqa: E402
 
@@ -142,7 +143,7 @@ def run_soak(config: str, kinds, n_ticks: int, seed: int = 0,
     cm = CostModel()
 
     st = {  # mutable harness state the rescale callback swaps out
-        "mesh": jax.make_mesh(*MESH_ARGS),
+        "mesh": make_mesh(*MESH_ARGS),
         "runtime": fault_runtime_for_mesh(*MESH_ARGS, TORUS, engine=engine),
         "params": make_params(),
     }
@@ -187,8 +188,8 @@ def run_soak(config: str, kinds, n_ticks: int, seed: int = 0,
         if keep < 2:
             return None
         sel = survivors[:keep]
-        devs = np.array(jax.devices())[sel].reshape(keep, 1)
-        st["mesh"] = jax.sharding.Mesh(devs, ("data", "model"))
+        st["mesh"] = make_mesh((keep, 1), ("data", "model"),
+                               devices=[jax.devices()[v] for v in sel])
         new_rt = fault_runtime_for_mesh((keep, 1), ("data", "model"),
                                         dp_torus_shape=_sub_torus(keep),
                                         engine=engine)
@@ -264,7 +265,7 @@ def run_soak(config: str, kinds, n_ticks: int, seed: int = 0,
 
     # fault-free psum_dp reference over the identical batch sequence, on
     # the original healthy mesh
-    ref_mesh = jax.make_mesh(*MESH_ARGS)
+    ref_mesh = make_mesh(*MESH_ARGS)
     ref = jax.jit(make_train_step(api, opt, ref_mesh, mode="psum_dp"))
     rp, rstate = make_params(), opt.init(make_params())
     ref_losses, ref_gnorms = [], []

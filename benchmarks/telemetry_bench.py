@@ -52,6 +52,7 @@ from repro.core.edst_star import star_edsts  # noqa: E402
 from repro.dist.striped import striped_allreduce  # noqa: E402
 from repro.dist.tree_allreduce import (pipelined_tree_allreduce,  # noqa: E402
                                        set_wave_scopes)
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.telemetry import timing  # noqa: E402
 
 FABRICS = (("torus4x4", (4, 4)), ("torus2x8", (2, 8)))
@@ -93,7 +94,7 @@ def bench_overhead(results: dict, elems: int, iters: int) -> None:
     module flag read at TRACE time, so each arm jits its own callable
     under the matching flag state and both executables are compiled
     before any timed call."""
-    mesh = jax.make_mesh((16,), ("data",))
+    mesh = make_mesh((16,), ("data",))
     x = (jnp.arange(16 * elems, dtype=jnp.float32).reshape(16, elems)
          * 1e-4)
     nbytes = elems * 4
@@ -128,7 +129,7 @@ def bench_overhead(results: dict, elems: int, iters: int) -> None:
 def bench_waves(results: dict, elems: int, iters: int) -> None:
     """Wave-by-wave measured-vs-predicted rows + the fitted calibration
     fed back into the CostModel registry."""
-    mesh = jax.make_mesh((16,), ("data",))
+    mesh = make_mesh((16,), ("data",))
     nbytes = elems * 4
     all_wires, all_meas = [], []
     for label, dims in FABRICS:

@@ -43,6 +43,7 @@ import repro.dist                                                 # noqa: E402
 from repro.core.fault import FailureEvent, rebalance_chunks       # noqa: E402
 from repro.dist.fault import NoScheduleError                      # noqa: E402
 from repro.dist.steps import fault_runtime_for_mesh               # noqa: E402
+from repro.launch.mesh import make_mesh                           # noqa: E402
 
 # 1. the elastic runtime: all failure-class programs precompiled ------------
 rt = fault_runtime_for_mesh((16, 1), ("data", "model"), dp_torus_shape=(4, 4))
@@ -54,7 +55,7 @@ for row in report["entries"]:
           f"depth={row['depth']:<3d} {row['gbps']:5.1f} GB/s")
 
 # 2. jitted switch: healthy run, then a link failure mid-run ----------------
-mesh = jax.make_mesh((16, 1), ("data", "model"))
+mesh = make_mesh((16, 1), ("data", "model"))
 sync = rt.make_allreduce()
 x = jnp.arange(16 * 37, dtype=jnp.float32).reshape(16, 37) * 0.01
 expect = jnp.tile(x.sum(0), (16, 1))

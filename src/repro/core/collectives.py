@@ -1394,7 +1394,7 @@ class CostModel:
         # unrolls with zero pipeline overhead.
         "cpu": {"link_bw": 2e8, "alpha": 5.5e-4, "overlap": False},
         # the class defaults model a real fabric (per-link DMA engines:
-        # waves on disjoint links overlap), calibrated against TPU ICI
+        # waves on disjoint links overlap); not measured on TPU ICI
         "tpu": {},
     }
     _WARNED_BACKENDS = set()

@@ -23,12 +23,7 @@ Four modules wire the paper's edge-disjoint-spanning-tree constructions
 
 See README.md in this directory for the data flow.
 """
-from . import compat as _compat
-
-_compat.install()
-
-from . import (fault, pipeline, sharding, steps,  # noqa: E402
-               striped, tree_allreduce)
+from . import fault, pipeline, sharding, steps, striped, tree_allreduce
 
 __all__ = ["sharding", "steps", "striped", "tree_allreduce", "pipeline",
            "fault"]

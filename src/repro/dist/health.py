@@ -50,7 +50,6 @@ from jax.sharding import PartitionSpec as P
 from ..analysis.verify import engine_of
 from ..core.graph import canon
 from ..telemetry import metrics as _metrics
-from .compat import shard_map
 
 
 # ---------------------------------------------------------------------------
@@ -185,8 +184,8 @@ def mesh_link_probe(mesh, spec_or_runtime):
     ``run(fault_mask=None) -> np.ndarray (L,) of {0., 1.}`` executes the
     probe on ``mesh`` (mask defaults to all-ones)."""
     probe, plan = make_link_probe(spec_or_runtime)
-    fn = jax.jit(shard_map(probe, mesh=mesh, in_specs=(P(),),
-                           out_specs=P(), check_rep=False))
+    fn = jax.jit(jax.shard_map(probe, mesh=mesh, in_specs=(P(),),
+                               out_specs=P(), check_vma=False))
     ones = np.ones(plan.num_links, np.float32)
 
     def run(fault_mask=None):

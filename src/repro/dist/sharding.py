@@ -105,6 +105,19 @@ def owner_stripe_spec(mesh) -> PartitionSpec:
     return PartitionSpec(_dp_entry(names))
 
 
+def train_state_shardings(axes_tree, params_tree, mesh, fsdp: bool = True):
+    """``(params, AdamW state)`` NamedSharding trees for the dense train
+    step: params by :func:`tree_shardings`, the moments as their params,
+    the step count replicated.  Place the state with it and jit the step
+    with it as ``out_shardings``: the new state then comes back in the
+    layout it went in with, and the jitted step keeps one input
+    signature (no retrace, no recompile) from step to step."""
+    from ..optim.adamw import OptState
+    pshard = tree_shardings(axes_tree, params_tree, mesh, fsdp=fsdp)
+    rep = jax.sharding.NamedSharding(mesh, PartitionSpec())
+    return pshard, OptState(rep, pshard, pshard)
+
+
 def zero1_state_shardings(opt_state, mesh):
     """NamedSharding tree for a :class:`repro.optim.sharded.ShardedOptState`:
     ``mu`` / ``nu`` take :func:`owner_stripe_spec`, the scalar step

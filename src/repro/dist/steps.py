@@ -54,7 +54,6 @@ from ..core.collectives import (FusedAllreduceSpec, PipelinedAllreduceSpec,
                                 striped_spec_from_schedule, wave_wire_bytes)
 from ..core.edst_star import star_edsts
 from . import sharding as shd
-from .compat import shard_map
 from .fault import FaultAwareAllreduce
 from .striped import stripe_slices, tree_allgather, tree_reduce_scatter
 from .tree_allreduce import tree_allreduce
@@ -304,11 +303,11 @@ def make_train_step(api, opt, mesh, mode: str = "gspmd", fsdp: bool = True,
                     return tree_allgather(owned, tree_spec, shape)
 
     # FSDP is expressed through the shardings callers place params/opt state
-    # with (``sharding.tree_shardings(..., fsdp=fsdp)``, e.g. as jit
-    # in_shardings) -- the step body itself adds no sharding constraints:
-    # on this jaxlib, in-step constraints propagate into the remat'd scan
-    # backward and the SPMD partitioner miscompiles it (wrong gradients
-    # alongside "Involuntary full rematerialization" warnings).
+    # with and jit the step with (``sharding.train_state_shardings`` as
+    # in/out shardings) -- the step body itself adds no sharding
+    # constraints.  JAX 0.4.x miscompiled in-step constraints in the
+    # remat'd scan backward (wrong gradients alongside "Involuntary full
+    # rematerialization" warnings); that has not been re-tested on 0.9.
     del fsdp
 
     def _tree_grad_norm(grads):
@@ -403,17 +402,17 @@ def make_train_step(api, opt, mesh, mode: str = "gspmd", fsdp: bool = True,
         # Fully-manual shard_map: params replicate and the model axis is
         # unused inside, so TP/FSDP do not compose with the manual sync
         # modes here.  Keeping only the DP axes Manual (axis_names=set(dp))
-        # is the right composition but hard-crashes this jaxlib's XLA
-        # ("Check failed: sharding.IsManualSubgroup()") on the remat'd scan
-        # -- revisit when the toolchain moves past 0.4.x.  Production
-        # TP+FSDP meshes should use mode="gspmd" meanwhile.
+        # is the right composition, but JAX 0.4.x's XLA hard-crashed on it
+        # ("Check failed: sharding.IsManualSubgroup()") in the remat'd
+        # scan; not re-tested on 0.9.  Production TP+FSDP meshes use
+        # mode="gspmd" meanwhile.
         if schedule_id is None:
             schedule_id = jnp.int32(0)
         outs = (P(), P(), P()) + ((P(),) if telemetry else ())
-        return shard_map(local, mesh=mesh,
-                         in_specs=(P(), P(dp_arg), P()),
-                         out_specs=outs,
-                         check_rep=False)(params, batch, schedule_id)
+        return jax.shard_map(local, mesh=mesh,
+                             in_specs=(P(), P(dp_arg), P()),
+                             out_specs=outs,
+                             check_vma=False)(params, batch, schedule_id)
 
     if zero1:
         sopt = ShardedAdamW(opt)
@@ -454,11 +453,11 @@ def make_train_step(api, opt, mesh, mode: str = "gspmd", fsdp: bool = True,
         def _zstep(params, opt_state, batch, schedule_id=None):
             if schedule_id is None:
                 schedule_id = jnp.int32(0)
-            loss, aux, new_params, new_mu, new_nu, om = shard_map(
+            loss, aux, new_params, new_mu, new_nu, om = jax.shard_map(
                 zero1_local, mesh=mesh,
                 in_specs=(P(), P(dp_arg), P(), P(), P(dp_arg), P(dp_arg)),
                 out_specs=(P(), P(), P(), P(dp_arg), P(dp_arg), P()),
-                check_rep=False)(params, batch, schedule_id,
+                check_vma=False)(params, batch, schedule_id,
                                  opt_state.step, opt_state.mu, opt_state.nu)
             new_state = ShardedOptState(opt_state.step + 1, new_mu, new_nu)
             metrics = {"loss": loss, **om, **aux}

@@ -697,8 +697,13 @@ def _scanned(rows, spec, idx, axis, segments, msub, codec, dtype):
     stage = [w if (codec is None or w < boundary) else w + 1
              for w in range(len(waves))]
     nsteps = (len(waves) if codec is None else len(waves) + 1) + segments - 1
-    pst = jnp.zeros((k, segments, msub + 4), jnp.int8) if codec is not None \
-        else None
+    pst = None
+    if codec is not None:
+        # the packed carry varies over the manual axes as ``st`` does
+        pst = jnp.zeros((k, segments, msub + 4), jnp.int8)
+        vma = tuple(jax.typeof(st).vma)
+        if vma:
+            pst = jax.lax.pcast(pst, vma, to="varying")
 
     def seg_slice(arr, j, seg):
         return jax.lax.dynamic_slice(

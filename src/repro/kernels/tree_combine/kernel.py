@@ -9,13 +9,9 @@ chunks are bf16 on the wire when quantization is off).
 ``q8_pack_wire`` / ``q8_combine_wire`` / ``q8_unpack_wire``: the quantized
 wire format is ``(L + 4,) int8`` -- L quantized lanes followed by the
 per-chunk f32 scale bit-packed into a 4-byte tail, so a quantized hop is
-ONE ppermute payload.  Pack (quantize + tail write), unpack+accumulate
-(dequantize fused into the partial-sum add) and plain unpack each run as
-a single kernel, replacing the separate quantize / bitcast / concatenate
-/ dequantize XLA op chains that made the q8 path a regression.  The wire
-kernels process the whole buffer as one VMEM block; callers fall back to
-the reference for buffers beyond VMEM reach (``ops.combine`` handles the
-dispatch).
+ONE ppermute payload.  Pack (quantize), unpack+accumulate (dequantize
+fused into the partial-sum add) and plain unpack each stream the lanes
+through one gridded kernel, at any payload size.
 """
 from __future__ import annotations
 
@@ -24,6 +20,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _combine_kernel(recv_ref, part_ref, o_ref):
@@ -59,67 +56,97 @@ def tree_combine(recv, partial, *, tile=65536, interpret=False):
 # ---------------------------------------------------------------------------
 # int8 wire codec
 # ---------------------------------------------------------------------------
+#
+# Mosaic cannot bitcast between bit widths inside a kernel, so the 4-byte
+# scale tail of the wire is written and read by XLA (a 4-byte update or
+# slice of the wire buffer, no copy of the lanes) and the kernels take the
+# scale -- or its reciprocal, computed by the same XLA op as the
+# reference's -- as an SMEM scalar.  Each kernel is a 1D grid over
+# ``tile``-lane blocks of the L lanes.  A last block that runs past L
+# reads lanes it never stores, so the grid needs no padding copy and
+# serves every payload size.
 
-def _scale_tail(scale):
-    return jax.lax.bitcast_convert_type(scale.astype(jnp.float32), jnp.int8)
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
+_LANES = 128
 
 
-def _tail_scale(tail):
-    return jax.lax.bitcast_convert_type(tail, jnp.float32)
+def _lane_grid(l, tile):
+    """(block, steps): blocks of ``tile`` lanes, or one block of L
+    rounded up to whole vector lanes when L is smaller."""
+    tl = min(tile, -(-l // _LANES) * _LANES)
+    return tl, pl.cdiv(l, tl)
 
 
-def _q8_pack_kernel(x_ref, s_ref, o_ref):
-    l = x_ref.shape[0]
-    scale = s_ref[0]
+def _lanes(tl):
+    return pl.BlockSpec((tl,), lambda i: (i,))
+
+
+def _q8_pack_kernel(inv_ref, x_ref, o_ref):
     # |x| <= 127 * scale by construction of the scale, so no clip needed
-    o_ref[:l] = jnp.round(x_ref[...].astype(jnp.float32)
-                          * (1.0 / scale)).astype(jnp.int8)
-    o_ref[l:] = _scale_tail(scale)
+    o_ref[...] = jnp.round(x_ref[...].astype(jnp.float32)
+                           * inv_ref[0]).astype(jnp.int8)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def q8_pack_wire(x, scale, *, interpret=False):
+@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+def q8_pack_wire(x, scale, *, tile=1 << 18, interpret=False):
     """x: (L,) float, scale: () f32 with max|x| <= 127*scale -> (L+4,) int8
-    wire buffer (quantized lanes + bit-packed scale tail), one kernel."""
+    wire buffer (quantized lanes + bit-packed scale tail)."""
     (l,) = x.shape
-    return pl.pallas_call(
+    scale = scale.astype(jnp.float32)
+    tl, steps = _lane_grid(l, tile)
+    wire = pl.pallas_call(
         _q8_pack_kernel,
+        grid=(steps,),
+        in_specs=[_SMEM, _lanes(tl)],
+        out_specs=_lanes(tl),
         out_shape=jax.ShapeDtypeStruct((l + 4,), jnp.int8),
         interpret=interpret,
-    )(x, scale.reshape(1))
+    )((1.0 / scale).reshape(1), x)
+    return wire.at[l:].set(jax.lax.bitcast_convert_type(scale, jnp.int8))
 
 
-def _q8_combine_kernel(w_ref, part_ref, o_ref):
-    l = part_ref.shape[0]
-    scale = _tail_scale(w_ref[l:])
+def _wire_scale(wire):
+    return jax.lax.bitcast_convert_type(wire[-4:], jnp.float32).reshape(1)
+
+
+def _q8_combine_kernel(s_ref, w_ref, part_ref, o_ref):
     o_ref[...] = (part_ref[...].astype(jnp.float32)
-                  + w_ref[:l].astype(jnp.float32) * scale).astype(o_ref.dtype)
+                  + w_ref[...].astype(jnp.float32) * s_ref[0]
+                  ).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def q8_combine_wire(wire, partial, *, interpret=False):
-    """partial + dequantize(wire): the quantize-aware combine -- scale
-    extraction, dequantize and accumulate fused into one kernel."""
+@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+def q8_combine_wire(wire, partial, *, tile=1 << 18, interpret=False):
+    """partial + dequantize(wire): the quantize-aware combine, dequantize
+    and accumulate fused into one pass over the lanes."""
     (l,) = partial.shape
+    tl, steps = _lane_grid(l, tile)
     return pl.pallas_call(
         _q8_combine_kernel,
+        grid=(steps,),
+        in_specs=[_SMEM, _lanes(tl), _lanes(tl)],
+        out_specs=_lanes(tl),
         out_shape=jax.ShapeDtypeStruct((l,), partial.dtype),
         interpret=interpret,
-    )(wire, partial)
+    )(_wire_scale(wire), wire, partial)
 
 
-def _q8_unpack_kernel(w_ref, o_ref):
-    l = o_ref.shape[0]
-    scale = _tail_scale(w_ref[l:])
-    o_ref[...] = (w_ref[:l].astype(jnp.float32) * scale).astype(o_ref.dtype)
+def _q8_unpack_kernel(s_ref, w_ref, o_ref):
+    o_ref[...] = (w_ref[...].astype(jnp.float32) * s_ref[0]
+                  ).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("dtype", "interpret"))
-def q8_unpack_wire(wire, dtype=jnp.float32, *, interpret=False):
+@functools.partial(jax.jit, static_argnames=("dtype", "tile", "interpret"))
+def q8_unpack_wire(wire, dtype=jnp.float32, *, tile=1 << 18,
+                   interpret=False):
     """Plain dequantize of a wire buffer (the broadcast-phase epilogue)."""
-    (lw,) = wire.shape
+    l = wire.shape[0] - 4
+    tl, steps = _lane_grid(l, tile)
     return pl.pallas_call(
         _q8_unpack_kernel,
-        out_shape=jax.ShapeDtypeStruct((lw - 4,), dtype),
+        grid=(steps,),
+        in_specs=[_SMEM, _lanes(tl)],
+        out_specs=_lanes(tl),
+        out_shape=jax.ShapeDtypeStruct((l,), dtype),
         interpret=interpret,
-    )(wire)
+    )(_wire_scale(wire), wire)

@@ -118,11 +118,10 @@ def build_cell(arch: str, shape_name: str, mesh, sync_mode: str = "gspmd",
               for k, v in batch_shapes.items()}
 
     if shape.kind == "train":
-        from repro.optim.adamw import OptState
         opt = AdamW(cosine_schedule(3e-4, 100, 10_000))
         oshapes = jax.eval_shape(opt.init, pshapes)
-        oshard = OptState(jax.sharding.NamedSharding(
-            mesh, jax.sharding.PartitionSpec()), pshard, pshard)
+        pshard, oshard = shd.train_state_shardings(paxes, pshapes, mesh,
+                                                   fsdp=fsdp)
         step_fn = make_train_step(api, opt, mesh, mode=sync_mode, fsdp=fsdp)
         return step_fn, (pshapes, oshapes, batch_shapes), \
             (pshard, oshard, bshard)

@@ -41,6 +41,8 @@ from repro.dist.chaos import out_of_class_burst
 from repro.dist.fault import FaultAwareAllreduce, NoScheduleError
 from repro.dist.steps import (dp_axes_of, edst_spec_for_mesh,
                               fault_runtime_for_mesh)
+from repro.launch.cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.models.api import build
 from repro.optim import AdamW, cosine_schedule
 
@@ -246,6 +248,7 @@ def main(argv=None):
                          "(out-of-class with_rebuild), node (elastic "
                          "rescale)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.failure_drill:
         dims = tuple(int(x) for x in args.to_mesh.split(","))
@@ -266,7 +269,7 @@ def main(argv=None):
     api = build(cfg)
     dims = tuple(int(x) for x in args.to_mesh.split(","))
     names = ("pod", "data", "model")[-len(dims):]
-    mesh = jax.make_mesh(dims, names)
+    mesh = make_mesh(dims, names)
     opt = AdamW(cosine_schedule(3e-4, 10, 100))
     params, opt_state, step = reshard_checkpoint(api, opt, args.ckpt_dir, mesh)
     spec = rebuild_schedule(mesh)

@@ -27,6 +27,8 @@ from repro.core.collectives import owner_element_map
 from repro.data import SyntheticLMStream
 from repro.dist import sharding as shd
 from repro.dist.steps import dp_size, edst_spec_for_mesh, make_train_step
+from repro.launch.cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.models.api import build
 from repro.optim import AdamW, ShardedAdamW, cosine_schedule
 from repro.optim.adamw import OptState
@@ -106,19 +108,20 @@ def main(argv=None):
         ap.error("--recover requires --sync edst without --zero1 (the "
                  "zero1 recovery loop lives in benchmarks/chaos_soak.py)")
 
+    enable_compile_cache()
     cfg = configs.get(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
     api = build(cfg)
     dims, names = parse_mesh(args.mesh)
-    mesh = jax.make_mesh(dims, names)
+    mesh = make_mesh(dims, names)
     opt = AdamW(cosine_schedule(args.lr, args.warmup, args.steps))
 
     key = jax.random.PRNGKey(args.seed)
     with jax.set_mesh(mesh):
         params, axes = api.init(key)
-        pshard = shd.tree_shardings(axes, params, mesh)
-        params = jax.tree.map(jax.device_put, params, pshard)
+        pshard, oshard = shd.train_state_shardings(axes, params, mesh)
+        params = jax.device_put(params, pshard)
         zspec = zmap = None
         if args.zero1:
             zspec = edst_spec_for_mesh(dims, names, engine="striped")
@@ -127,11 +130,10 @@ def main(argv=None):
             zmap = owner_element_map(zspec, psize)
             opt_state = ShardedAdamW(opt).init_for(
                 params, zspec, dp_size(mesh))
-            opt_state = jax.tree.map(
-                jax.device_put, opt_state,
-                shd.zero1_state_shardings(opt_state, mesh))
+            oshard = shd.zero1_state_shardings(opt_state, mesh)
         else:
             opt_state = opt.init(params)
+        opt_state = jax.device_put(opt_state, oshard)
 
         runtime = monitor = ctrl = None
         if args.recover and dp_size(mesh) > 1:
@@ -151,7 +153,11 @@ def main(argv=None):
                                   telemetry=runtime is not None)
         # rollback on a suspect step needs the pre-step buffers alive
         donate = () if ctrl is not None else (0, 1)
-        jstep = jax.jit(step_fn, donate_argnums=donate)
+        # the new state comes back in the layout it went in with, so every
+        # step after the first reuses the first step's executable
+        out_shardings = (pshard, oshard, None)
+        jstep = jax.jit(step_fn, donate_argnums=donate,
+                        out_shardings=out_shardings)
 
         if args.trace_out:
             if args.sync != "edst" or dp_size(mesh) < 2:
@@ -176,12 +182,12 @@ def main(argv=None):
         if args.ckpt_dir and latest_step(args.ckpt_dir) is not None:
             if args.zero1:
                 params, opt_state, start, extra = restore_sharded(
-                    args.ckpt_dir, params, zmap,
-                    state_shardings=shd.zero1_state_shardings(
-                        opt_state, mesh))
+                    args.ckpt_dir, params, zmap, param_shardings=pshard,
+                    state_shardings=oshard)
             else:
-                state, start, extra = restore(args.ckpt_dir,
-                                              {"p": params, "o": opt_state})
+                state, start, extra = restore(
+                    args.ckpt_dir, {"p": params, "o": opt_state},
+                    shardings={"p": pshard, "o": oshard})
                 params, opt_state = state["p"], state["o"]
             print(f"[train] resumed from step {start}")
 
@@ -231,7 +237,8 @@ def main(argv=None):
                             quantize=args.quantize_grads,
                             engine=args.edst_engine,
                             fault_runtime=ctrl.runtime, telemetry=True)
-                        jstep = jax.jit(step_fn)
+                        jstep = jax.jit(step_fn,
+                                        out_shardings=out_shardings)
                         monitor = HealthMonitor(mesh, ctrl.runtime,
                                                 straggler=monitor.straggler)
                     if dec.backoff_s:
@@ -245,8 +252,8 @@ def main(argv=None):
             if step % args.log_every == 0 or step == args.steps - 1:
                 dt = time.time() - t0
                 print(f"[train] step {step:5d} loss {losses[-1]:.4f} "
-                      f"gnorm {float(metrics['grad_norm']):.3f} "
-                      f"lr {float(metrics['lr']):.2e} ({dt:.1f}s)")
+                      f"gnorm {float(metrics['grad_norm']):.6g} "
+                      f"lr {float(metrics['lr']):.2e} ({dt:.3f}s)")
             if args.ckpt_dir and (step + 1) % args.ckpt_every == 0:
                 _save(args, step + 1, params, opt_state, zmap)
             step += 1

@@ -60,7 +60,8 @@ def _mesh_for(spec):
             f"{jax.device_count()}; call telemetry.timing.ensure_devices"
             f"({spec.n}) (or set XLA_FLAGS=--xla_force_host_platform_"
             f"device_count={spec.n}) before anything imports jax")
-    return jax.make_mesh((spec.n,), (spec.axes[0],))
+    from ..launch.mesh import make_mesh
+    return make_mesh((spec.n,), (spec.axes[0],))
 
 
 def _jit_wave(step, mesh, nstate: int):

@@ -4,6 +4,7 @@ import jax
 import pytest
 
 from repro.dist.sharding import spec_for, tree_shardings
+from repro.launch.mesh import make_mesh
 
 P = jax.sharding.PartitionSpec
 
@@ -104,7 +105,7 @@ def test_batch_maps_to_all_dp_axes():
 # -- tree_shardings -----------------------------------------------------------
 
 def test_tree_shardings_structure_and_cache_pairs():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     params = {"w": jax.ShapeDtypeStruct((64, 128), jax.numpy.float32),
               "scale": jax.ShapeDtypeStruct((64,), jax.numpy.float32),
               "cache": (jax.ShapeDtypeStruct((2, 8, 4, 16), jax.numpy.float32),

@@ -7,13 +7,13 @@ import os
 assert "XLA_FLAGS" in os.environ
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-import repro.dist  # installs compat shard_map
 from repro.core.fault import FailureEvent
 from repro.dist.steps import fault_runtime_for_mesh
+from repro.launch.mesh import make_mesh
 
 rt = fault_runtime_for_mesh((16, 1), ('data', 'model'), dp_torus_shape=(4, 4))
 assert rt.k == 2 and len(rt.entries) == 5
-mesh = jax.make_mesh((16, 1), ('data', 'model'))
+mesh = make_mesh((16, 1), ('data', 'model'))
 sync = rt.make_allreduce()
 
 x = jnp.arange(16 * 53, dtype=jnp.float32).reshape(16, 53) * 0.01
@@ -62,10 +62,11 @@ from repro.core.fault import FailureEvent
 from repro.models.api import build
 from repro.dist.steps import fault_runtime_for_mesh, make_train_step
 from repro.optim import AdamW, cosine_schedule
+from repro.launch.mesh import make_mesh
 
 cfg = configs.get('smollm-135m').reduced()
 api = build(cfg)
-mesh = jax.make_mesh((16, 1), ('data', 'model'))
+mesh = make_mesh((16, 1), ('data', 'model'))
 rt = fault_runtime_for_mesh((16, 1), ('data', 'model'), dp_torus_shape=(4, 4))
 opt = AdamW(cosine_schedule(1e-3, 10, 100))
 params, _ = api.init(jax.random.PRNGKey(0))

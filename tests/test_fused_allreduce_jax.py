@@ -9,7 +9,6 @@ assert "XLA_FLAGS" in os.environ
 import numpy as np
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-import repro.dist  # installs compat shard_map
 from repro.core import topologies as topo
 from repro.core.edst_star import star_edsts
 from repro.core.collectives import (allreduce_schedule,
@@ -18,8 +17,9 @@ from repro.core.collectives import (allreduce_schedule,
 from repro.dist.tree_allreduce import (fused_tree_allreduce,
                                        per_tree_allreduce,
                                        spec_from_schedule)
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((4, 4), ('a', 'b'))
+mesh = make_mesh((4, 4), ('a', 'b'))
 x = jnp.arange(16 * 53, dtype=jnp.float32).reshape(16, 53) * 0.01
 expect = x.sum(0)
 
@@ -92,8 +92,9 @@ from repro.core.edst_star import star_edsts
 from repro.core.collectives import (allreduce_schedule,
                                     fused_spec_from_schedule)
 from repro.dist.tree_allreduce import fused_tree_allreduce
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((4, 4), ('a', 'b'))
+mesh = make_mesh((4, 4), ('a', 'b'))
 x = jnp.arange(16 * 53, dtype=jnp.float32).reshape(16, 53) * 0.01
 
 @functools.partial(jax.jit, static_argnums=(1,))

@@ -124,9 +124,13 @@ def test_tree_combine_kernel(nch, l, tile, dtype):
 
 # -- int8 wire codec ----------------------------------------------------------
 
-@pytest.mark.parametrize("l", [64, 1000, 4096])
+@pytest.mark.parametrize("l,tile", [(64, 1 << 18), (1000, 1 << 18),
+                                    (4096, 1 << 18), (1000, 256),
+                                    (4097, 1024), (17, 128)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_q8_wire_kernels_match_refs(l, dtype):
+def test_q8_wire_kernels_match_refs(l, tile, dtype):
+    """Gridded codec kernels equal the jitted references bit for bit,
+    with one block, many blocks, and a last block that runs past L."""
     from repro.kernels.tree_combine.kernel import (q8_combine_wire,
                                                    q8_pack_wire,
                                                    q8_unpack_wire)
@@ -134,18 +138,18 @@ def test_q8_wire_kernels_match_refs(l, dtype):
                                                 q8_scale, q8_unpack_ref)
     x = rand((l,), dtype, 1) * 3.3
     s = q8_scale(x)
-    wire_k = q8_pack_wire(x, s, interpret=True)
-    wire_r = q8_pack_ref(x, s)
+    wire_k = q8_pack_wire(x, s, tile=tile, interpret=True)
+    wire_r = jax.jit(q8_pack_ref)(x, s)
     assert wire_k.dtype == jnp.int8 and wire_k.shape == (l + 4,)
     assert (jnp.asarray(wire_k) == jnp.asarray(wire_r)).all()
 
     part = rand((l,), jnp.float32, 2)
-    out_k = q8_combine_wire(wire_k, part, interpret=True)
-    assert float(jnp.max(jnp.abs(out_k - q8_combine_ref(wire_r, part)))) < 1e-6
+    out_k = q8_combine_wire(wire_k, part, tile=tile, interpret=True)
+    assert (out_k == jax.jit(q8_combine_ref)(wire_r, part)).all()
 
-    dec_k = q8_unpack_wire(wire_k, jnp.float32, interpret=True)
-    dec_r = q8_unpack_ref(wire_r, jnp.float32)
-    assert float(jnp.max(jnp.abs(dec_k - dec_r))) < 1e-6
+    dec_k = q8_unpack_wire(wire_k, jnp.float32, tile=tile, interpret=True)
+    dec_r = jax.jit(q8_unpack_ref)(wire_r)
+    assert (dec_k == dec_r).all()
     # quantization round-trip error bounded by half a step
     assert float(jnp.max(jnp.abs(dec_r - x.astype(jnp.float32)))) \
         <= float(s) * 0.51

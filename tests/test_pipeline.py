@@ -4,9 +4,10 @@ CODE = r"""
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.dist.pipeline import pipeline_apply
+from repro.launch.mesh import make_mesh
 
 n_stages, n_micro, mb, d = 4, 8, 2, 16
-mesh = jax.make_mesh((n_stages,), ('stage',))
+mesh = make_mesh((n_stages,), ('stage',))
 key = jax.random.PRNGKey(0)
 ws = jax.random.normal(key, (n_stages, d, d)) * 0.3
 x = jax.random.normal(jax.random.PRNGKey(1), (n_micro, mb, d))

@@ -10,15 +10,15 @@ assert "XLA_FLAGS" in os.environ
 import numpy as np
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-import repro.dist  # installs compat shard_map
 from repro.core import topologies as topo
 from repro.core.edst_star import star_edsts
 from repro.core.collectives import (allreduce_schedule,
                                     pipelined_spec_from_schedule,
                                     simulate_wave_program)
 from repro.dist.tree_allreduce import pipelined_tree_allreduce
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((4, 4), ('a', 'b'))
+mesh = make_mesh((4, 4), ('a', 'b'))
 
 
 def smapped(body):
@@ -95,8 +95,9 @@ from repro.core.edst_star import star_edsts
 from repro.core.collectives import (allreduce_schedule,
                                     pipelined_spec_from_schedule)
 from repro.dist.tree_allreduce import pipelined_tree_allreduce
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((4, 4), ('a', 'b'))
+mesh = make_mesh((4, 4), ('a', 'b'))
 x = jnp.arange(16 * 53, dtype=jnp.float32).reshape(16, 53) * 0.01
 
 
@@ -156,8 +157,9 @@ from repro.core.edst_star import star_edsts
 from repro.core.collectives import (allreduce_schedule,
                                     pipelined_spec_from_schedule)
 from repro.dist.tree_allreduce import pipelined_tree_allreduce
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((4, 4), ('a', 'b'))
+mesh = make_mesh((4, 4), ('a', 'b'))
 x = jnp.arange(16 * 53, dtype=jnp.float32).reshape(16, 53) * 0.01
 
 
@@ -195,11 +197,12 @@ import repro.dist
 from repro.core.collectives import PipelinedAllreduceSpec
 from repro.core.fault import FailureEvent
 from repro.dist.steps import fault_runtime_for_mesh
+from repro.launch.mesh import make_mesh
 
 rt = fault_runtime_for_mesh((16, 1), ('data', 'model'), dp_torus_shape=(4, 4))
 # the elastic runtime's precompiled programs are pipelined specs now
 assert all(isinstance(e.spec, PipelinedAllreduceSpec) for e in rt.entries)
-mesh = jax.make_mesh((16, 1), ('data', 'model'))
+mesh = make_mesh((16, 1), ('data', 'model'))
 sync = rt.make_allreduce(quantize=True, segments=2)  # scan path in-switch
 
 x = jnp.arange(16 * 53, dtype=jnp.float32).reshape(16, 53) * 0.01

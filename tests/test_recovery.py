@@ -207,11 +207,11 @@ def test_check_schedule_id_names_the_violation(rt):
 DEBUG_SID_CODE = r"""
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-import repro.dist  # installs compat shard_map
 from repro.dist.steps import fault_runtime_for_mesh
+from repro.launch.mesh import make_mesh
 
 rt = fault_runtime_for_mesh((4, 1), ('data', 'model'), dp_torus_shape=(2, 2))
-mesh = jax.make_mesh((4, 1), ('data', 'model'))
+mesh = make_mesh((4, 1), ('data', 'model'))
 
 def harness(sync):
     def body(xs, sid):

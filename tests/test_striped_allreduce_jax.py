@@ -9,15 +9,15 @@ CODE = r"""
 import numpy as np
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-import repro.dist  # installs compat shard_map
 from repro.core import topologies as topo
 from repro.core.edst_star import star_edsts
 from repro.core.collectives import (allreduce_schedule,
                                     simulate_striped_program,
                                     striped_spec_from_schedule)
 from repro.dist.striped import striped_allreduce
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((16,), ('data',))
+mesh = make_mesh((16,), ('data',))
 
 
 def smapped(body):
@@ -90,8 +90,9 @@ from repro.core.collectives import (allreduce_schedule,
                                     striped_spec_from_schedule)
 from repro.dist.striped import (stripe_layout, striped_allreduce,
                                 tree_allgather, tree_reduce_scatter)
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((16,), ('data',))
+mesh = make_mesh((16,), ('data',))
 sp = topo.device_topology((4, 4))
 sched = allreduce_schedule(sp.n, star_edsts(sp).trees)
 spec = striped_spec_from_schedule(sched, ('data',))
@@ -150,6 +151,7 @@ from repro.core.collectives import StripedCollectiveSpec
 from repro.core.fault import FailureEvent
 from repro.dist.steps import edst_spec_for_mesh, fault_runtime_for_mesh
 from repro.dist.tree_allreduce import tree_allreduce
+from repro.launch.mesh import make_mesh
 
 # engine selection end to end: spec compile + generic dispatch
 spec = edst_spec_for_mesh((16, 1), ('data', 'model'),
@@ -162,7 +164,7 @@ rt = fault_runtime_for_mesh((16, 1), ('data', 'model'),
                             dp_torus_shape=(4, 4), engine="striped")
 assert rt.engine == "striped"
 assert all(isinstance(e.spec, StripedCollectiveSpec) for e in rt.entries)
-mesh = jax.make_mesh((16, 1), ('data', 'model'))
+mesh = make_mesh((16, 1), ('data', 'model'))
 sync = rt.make_allreduce()
 
 x = jnp.arange(16 * 53, dtype=jnp.float32).reshape(16, 53) * 0.01

@@ -12,6 +12,7 @@ from repro import configs
 from repro.ckpt import latest_step, restore, save_checkpoint
 from repro.data import SyntheticLMStream
 from repro.dist.sharding import spec_for
+from repro.launch.mesh import make_mesh
 from repro.launch.train import main as train_main
 from repro.optim import AdamW, cosine_schedule, global_norm_clip
 
@@ -67,7 +68,7 @@ def test_adamw_and_clip():
 
 
 def test_sharding_rules_divisibility():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
 
     class FakeMesh:
         axis_names = ("data", "model")
@@ -131,7 +132,7 @@ def test_grad_accumulation_matches_full_batch():
     from repro.dist.steps import make_train_step
     cfg = configs.get("smollm-135m").reduced()
     api = build(cfg)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     opt = AdamW(cosine_schedule(1e-3, 5, 50))
     params, _ = api.init(jax.random.PRNGKey(0))
     opt_state = opt.init(params)
@@ -160,12 +161,12 @@ def test_elastic_reshard_across_meshes(tmp_path):
     train_main(["--arch", "smollm-135m", "--reduced", "--steps", "4",
                 "--batch", "4", "--seq", "48", "--mesh", "1,1",
                 "--ckpt-dir", d, "--ckpt-every", "4", "--log-every", "100"])
-    mesh2 = jax.make_mesh((1, 1), ("data", "model"))
+    mesh2 = make_mesh((1, 1), ("data", "model"))
     params, opt_state, step = reshard_checkpoint(api, opt, d, mesh2)
     assert step == 4
     assert int(opt_state.step) == 4
     # single-data-shard mesh: no DP fabric, nothing to sync
-    assert rebuild_schedule(jax.make_mesh((1, 1), ("data", "model"))) is None
+    assert rebuild_schedule(make_mesh((1, 1), ("data", "model"))) is None
 
 
 # ---------------------------------------------------------------------------

@@ -12,6 +12,7 @@ import pytest
 from repro import configs
 from repro.analysis.verify import (ENGINES, PAPER_TOPOLOGIES,
                                    _compile_specs, _schedule_for)
+from repro.launch.mesh import make_mesh
 from repro.telemetry import metrics as tm
 from repro.telemetry import trace as tt
 
@@ -257,18 +258,23 @@ def test_journal_metric_reconciles_with_file(tmp_path):
 
 def test_telemetry_dict_single_device_no_retrace():
     """telemetry=True returns the structured sync metrics dict on the
-    non-manual path too, and two distinct batches reuse one trace."""
+    non-manual path too, and two distinct batches reuse one trace: the
+    state is placed and the step jitted as the train launcher does it."""
+    from repro.dist.sharding import train_state_shardings
     from repro.dist.steps import make_train_step
     from repro.models.api import build
     from repro.optim import AdamW, cosine_schedule
     cfg = configs.get("smollm-135m").reduced()
     api = build(cfg)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     opt = AdamW(cosine_schedule(1e-3, 5, 50))
-    params, _ = api.init(jax.random.PRNGKey(0))
+    params, axes = api.init(jax.random.PRNGKey(0))
     opt_state = opt.init(params)
+    shards = train_state_shardings(axes, params, mesh)
+    params, opt_state = jax.device_put((params, opt_state), shards)
     with jax.set_mesh(mesh):
-        jstep = jax.jit(make_train_step(api, opt, mesh, telemetry=True))
+        jstep = jax.jit(make_train_step(api, opt, mesh, telemetry=True),
+                        out_shardings=(*shards, None))
         for i in range(2):
             batch = {"tokens": jax.random.randint(
                 jax.random.PRNGKey(i), (8, 65), 0, cfg.vocab)}
@@ -289,10 +295,11 @@ from repro.core.collectives import wave_wire_bytes
 from repro.models.api import build
 from repro.dist.steps import fault_runtime_for_mesh, make_train_step
 from repro.optim import AdamW, cosine_schedule
+from repro.launch.mesh import make_mesh
 
 cfg = configs.get('smollm-135m').reduced()
 api = build(cfg)
-mesh = jax.make_mesh((16, 1), ('data', 'model'))
+mesh = make_mesh((16, 1), ('data', 'model'))
 rt = fault_runtime_for_mesh((16, 1), ('data', 'model'), dp_torus_shape=(4, 4))
 opt = AdamW(cosine_schedule(1e-3, 10, 100))
 params, _ = api.init(jax.random.PRNGKey(0))

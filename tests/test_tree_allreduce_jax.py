@@ -11,8 +11,9 @@ from repro.core import topologies as topo
 from repro.core.edst_star import star_edsts
 from repro.core.collectives import allreduce_schedule
 from repro.dist.tree_allreduce import spec_from_schedule, tree_allreduce
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((4, 4), ('a', 'b'))
+mesh = make_mesh((4, 4), ('a', 'b'))
 x = jnp.arange(16 * 53, dtype=jnp.float32).reshape(16, 53) * 0.01
 expect = x.sum(0)
 
@@ -40,10 +41,11 @@ from repro import configs
 from repro.models.api import build
 from repro.dist.steps import make_train_step
 from repro.optim import AdamW, cosine_schedule
+from repro.launch.mesh import make_mesh
 
 cfg = configs.get('smollm-135m').reduced()
 api = build(cfg)
-mesh = jax.make_mesh((4, 4), ('data', 'model'))
+mesh = make_mesh((4, 4), ('data', 'model'))
 opt = AdamW(cosine_schedule(1e-3, 10, 100))
 params, _ = api.init(jax.random.PRNGKey(0))
 opt_state = opt.init(params)
@@ -80,9 +82,10 @@ import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.dist.steps import edst_spec_for_mesh
 from repro.dist.tree_allreduce import tree_allreduce
+from repro.launch.mesh import make_mesh
 
 # pure-DP pod: 16 devices on the 'data' axis, physically a 4x4 torus
-mesh = jax.make_mesh((16, 1), ('data', 'model'))
+mesh = make_mesh((16, 1), ('data', 'model'))
 spec = edst_spec_for_mesh((16, 1), ('data', 'model'), dp_torus_shape=(4, 4))
 assert spec.k == 2, spec.k   # the 2D torus gives the maximal 2 EDSTs
 x = jnp.arange(16 * 19, dtype=jnp.float32).reshape(16, 19)

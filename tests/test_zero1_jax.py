@@ -39,6 +39,7 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.dist.steps import (make_train_step, edst_spec_for_mesh,
                               fault_runtime_for_mesh, dp_size)
 from repro.optim import AdamW, cosine_schedule, ShardedAdamW
+from repro.launch.mesh import make_mesh
 
 class QuadAPI:
     def loss_fn(self, params, batch):
@@ -63,7 +64,7 @@ def side_by_side(shapes=((6, 8), (5,)), steps=5, rtol_loss=1e-5,
                  rtol_g=1e-4, quantize=False, codec=None):
     """Train psum_dp and zero1 side by side; assert per-step agreement."""
     api, params, batch = make_problem(shapes)
-    mesh = jax.make_mesh(*MESH_ARGS)
+    mesh = make_mesh(*MESH_ARGS)
     opt = AdamW(cosine_schedule(1e-2, 2, 20))
     spec = edst_spec_for_mesh(*MESH_ARGS, TORUS, engine="striped")
     ref = jax.jit(make_train_step(api, opt, mesh, mode="psum_dp"))
@@ -95,7 +96,7 @@ def test_zero1_matches_psum_dp_under_link_kill():
 from repro.core.fault import FailureEvent
 
 api, params, batch = make_problem()
-mesh = jax.make_mesh(*MESH_ARGS)
+mesh = make_mesh(*MESH_ARGS)
 opt = AdamW(cosine_schedule(1e-2, 2, 20))
 rt = fault_runtime_for_mesh(*MESH_ARGS, TORUS, engine="striped")
 ref = jax.jit(make_train_step(api, opt, mesh, mode="psum_dp"))
@@ -148,7 +149,7 @@ from repro.analysis.verify import hlo_contract_for
 from repro.analysis.hlo import lint_hlo
 
 api, params, batch = make_problem()
-mesh = jax.make_mesh(*MESH_ARGS)
+mesh = make_mesh(*MESH_ARGS)
 opt = AdamW(cosine_schedule(1e-2, 2, 20))
 spec = edst_spec_for_mesh(*MESH_ARGS, TORUS, engine="striped")
 z = make_train_step(api, opt, mesh, mode="edst", zero1=True,
