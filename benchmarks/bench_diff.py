@@ -24,16 +24,6 @@ normalized cost grows by more than ``--threshold`` (default 1.25x).
 
     python -m benchmarks.bench_diff --baseline BENCH_allreduce_quick.json \
         --new /tmp/new.json --threshold 1.25
-
-A second, same-file mode gates telemetry overhead: ``--overhead FILE``
-pairs every ``telemetry/<fabric>/<engine>/scoped`` row with its
-``.../plain`` sibling from the SAME file (same process, interleaved
-timing, so nothing needs normalizing) and fails when the scoped build
-runs more than ``--threshold`` slower than the plain one -- the wave
-named-scopes are trace-time metadata and must stay free at run time.
-
-    python -m benchmarks.bench_diff --overhead BENCH_telemetry.json \
-        --threshold 1.05
 """
 from __future__ import annotations
 
@@ -76,58 +66,15 @@ def diff(baseline: dict, new: dict, threshold: float):
     return rows, regressions
 
 
-def overhead_diff(results: dict, threshold: float):
-    """Same-file scoped-vs-plain pairs: (rows, regressions) where rows
-    are (scoped_name, plain_us, scoped_us, ratio)."""
-    rows, regressions = [], []
-    for name in sorted(results):
-        if not (name.startswith("telemetry/") and name.endswith("/scoped")):
-            continue
-        plain = results.get(name[:-len("scoped")] + "plain")
-        if plain is None or plain["us_per_call"] <= 0:
-            continue
-        ratio = results[name]["us_per_call"] / plain["us_per_call"]
-        rows.append((name, plain["us_per_call"],
-                     results[name]["us_per_call"], ratio))
-        if ratio > threshold:
-            regressions.append(name)
-    return rows, regressions
-
-
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--baseline")
     ap.add_argument("--new")
     ap.add_argument("--threshold", type=float, default=1.25)
-    ap.add_argument("--overhead", metavar="FILE", default=None,
-                    help="same-file mode: gate telemetry/*/scoped rows "
-                         "against their /plain siblings in FILE")
     args = ap.parse_args()
 
-    if args.overhead:
-        with open(args.overhead) as f:
-            results = json.load(f)
-        rows, regressions = overhead_diff(results, args.threshold)
-        if not rows:
-            print("bench_diff: no telemetry/*/{plain,scoped} pairs in "
-                  f"{args.overhead}; an empty comparison disables the "
-                  "gate, so this is an error")
-            return 1
-        width = max(len(name) for name, *_ in rows)
-        print(f"{'row':<{width}}  {'plain(us)':>10} {'scoped(us)':>10} "
-              f"{'ratio':>7}")
-        for name, p, s, r in rows:
-            mark = "  <-- OVERHEAD" if name in regressions else ""
-            print(f"{name:<{width}}  {p:>10.1f} {s:>10.1f} {r:>7.3f}{mark}")
-        if regressions:
-            print(f"\n{len(regressions)} scoped row(s) above "
-                  f"{args.threshold:.2f}x their plain sibling")
-            return 1
-        print(f"\nscope overhead within {args.threshold:.2f}x on all rows")
-        return 0
-
     if not args.baseline or not args.new:
-        ap.error("--baseline and --new are required (or use --overhead)")
+        ap.error("--baseline and --new are required")
     with open(args.baseline) as f:
         baseline = json.load(f)
     with open(args.new) as f:

@@ -1353,7 +1353,8 @@ def wave_wire_bytes(spec, nbytes: float, itemsize: int = 4,
     the striped engine's waves carry their bound stripe-window widths
     (:func:`striped_tables`).  This is the static per-wave twin of the
     makespan methods below -- the telemetry layer renders it as span
-    widths and the timing harness diffs it against measurement."""
+    widths and the executors' trace hook sets the ``edst_wire_bytes``
+    gauge from it."""
     k = spec.k
     if k == 0:
         return ()
@@ -1493,11 +1494,12 @@ class CostModel:
         """Predicted seconds per wave, in program order: ``alpha +
         wire/bw`` over :func:`wave_wire_bytes`.  The per-wave
         decomposition of the makespan methods above -- what the
-        telemetry trace renders as predicted span durations and the
-        wave-by-wave timing harness (``repro.telemetry.timing``) diffs
-        against measurement.  ``segments`` > 1 (chunk engines only)
-        repeats the wave sequence once per segment at ``1/S`` of the row
-        bytes, the serialized-host reading of the streamed program."""
+        telemetry trace renders as predicted span durations, to be set
+        against the per-wave times of a device profile, whose ops carry
+        the executors' ``edst/t{j}/w{w}/{op}`` scopes.  ``segments`` > 1
+        (chunk engines only) repeats the wave sequence once per segment
+        at ``1/S`` of the row bytes, the serialized-host reading of the
+        streamed program."""
         wires = wave_wire_bytes(spec, nbytes, itemsize, fractions)
         if segments > 1 and not isinstance(spec, StripedCollectiveSpec):
             wires = tuple(-(-w // segments) for w in wires) * segments

@@ -329,8 +329,10 @@ def make_train_step(api, opt, mesh, mode: str = "gspmd", fsdp: bool = True,
         return jnp.float32(0.0)
 
     def loss_of(p, b):
-        loss, metrics = api.loss_fn(p, b)
-        return loss, metrics
+        # differentiated: the backward pass shows as
+        # transpose(jvp(step/model)), recomputation under it
+        with jax.named_scope("step/model"):
+            return api.loss_fn(p, b)
 
     vg = jax.value_and_grad(loss_of, has_aux=True)
 
@@ -372,31 +374,35 @@ def make_train_step(api, opt, mesh, mode: str = "gspmd", fsdp: bool = True,
 
         def local(p, b, sid):
             loss, aux, grads = local_loss_and_grads(p, b)
-            loss = jax.lax.pmean(loss, dp_arg)
-            aux = jax.tree.map(lambda a: jax.lax.pmean(a, dp_arg), aux)
-            if mode == "psum_dp":
-                grads = jax.tree.map(
-                    lambda g: jax.lax.psum(g, dp_arg) / ndp, grads)
-                flat = ravel_pytree(grads)[0] if telemetry else None
-            else:
-                flat, unravel = ravel_pytree(grads)
-                if fault_sync is not None:
-                    flat = fault_sync(flat, sid)
+            with jax.named_scope("step/sync"):
+                loss = jax.lax.pmean(loss, dp_arg)
+                aux = jax.tree.map(lambda a: jax.lax.pmean(a, dp_arg), aux)
+                if mode == "psum_dp":
+                    grads = jax.tree.map(
+                        lambda g: jax.lax.psum(g, dp_arg) / ndp, grads)
+                    flat = ravel_pytree(grads)[0] if telemetry else None
                 else:
-                    flat = tree_allreduce(flat, tree_spec, quantize=quantize,
-                                          segments=segments)
-                grads = unravel(flat / ndp)
-            if telemetry:
-                from .health import payload_checksum, replication_divergence
-                dev = replication_divergence(payload_checksum(flat), dp_arg)
-                itemsize = jnp.dtype(flat.dtype).itemsize
-                wire = (_wire_gauge(flat.size * itemsize, itemsize, sid)
-                        if mode == "edst" else jnp.float32(0.0))
-                return loss, aux, grads, {
-                    "sync_dev": dev,
-                    "sync_grad_norm": _tree_grad_norm(grads),
-                    "sync_schedule_id": jnp.asarray(sid, jnp.int32),
-                    "sync_wire_bytes": wire}
+                    flat, unravel = ravel_pytree(grads)
+                    if fault_sync is not None:
+                        flat = fault_sync(flat, sid)
+                    else:
+                        flat = tree_allreduce(flat, tree_spec,
+                                              quantize=quantize,
+                                              segments=segments)
+                    grads = unravel(flat / ndp)
+                if telemetry:
+                    from .health import (payload_checksum,
+                                         replication_divergence)
+                    dev = replication_divergence(payload_checksum(flat),
+                                                 dp_arg)
+                    itemsize = jnp.dtype(flat.dtype).itemsize
+                    wire = (_wire_gauge(flat.size * itemsize, itemsize, sid)
+                            if mode == "edst" else jnp.float32(0.0))
+                    return loss, aux, grads, {
+                        "sync_dev": dev,
+                        "sync_grad_norm": _tree_grad_norm(grads),
+                        "sync_schedule_id": jnp.asarray(sid, jnp.int32),
+                        "sync_wire_bytes": wire}
             return loss, aux, grads
 
         # Fully-manual shard_map: params replicate and the model axis is
@@ -423,21 +429,25 @@ def make_train_step(api, opt, mesh, mode: str = "gspmd", fsdp: bool = True,
             allgather of updated params only.  mu/nu arrive as this
             device's (1, kmax, smax) block of the global state."""
             loss, aux, grads = local_loss_and_grads(p, b)
-            loss = jax.lax.pmean(loss, dp_arg)
-            aux = jax.tree.map(lambda a: jax.lax.pmean(a, dp_arg), aux)
-            flat_g, _ = ravel_pytree(grads)
-            flat_p, unravel = ravel_pytree(p)
-            owned_g = z_rs(flat_g, sid) / ndp
-            f32 = flat_p.astype(jnp.float32)
-            owned_p = z_sl(f32, sid)
-            owned_d = z_sl(decay_mask(p, opt.weight_decay), sid)
-            new_count = step_count + 1
-            gnorm = jnp.sqrt(jax.lax.psum(sopt.partial_sumsq(owned_g),
-                                          dp_arg))
-            new_op, new_mu, new_nu, lr = sopt.update_stripes(
-                owned_p, owned_g, owned_d, mu[0], nu[0], new_count, gnorm)
-            new_flat = z_ag(new_op, sid, f32.shape)
-            new_params = unravel(new_flat.astype(flat_p.dtype))
+            with jax.named_scope("step/sync"):
+                loss = jax.lax.pmean(loss, dp_arg)
+                aux = jax.tree.map(lambda a: jax.lax.pmean(a, dp_arg), aux)
+                flat_g, _ = ravel_pytree(grads)
+                owned_g = z_rs(flat_g, sid) / ndp
+            with jax.named_scope("step/optimizer"):
+                flat_p, unravel = ravel_pytree(p)
+                f32 = flat_p.astype(jnp.float32)
+                owned_p = z_sl(f32, sid)
+                owned_d = z_sl(decay_mask(p, opt.weight_decay), sid)
+                new_count = step_count + 1
+                gnorm = jnp.sqrt(jax.lax.psum(sopt.partial_sumsq(owned_g),
+                                              dp_arg))
+                new_op, new_mu, new_nu, lr = sopt.update_stripes(
+                    owned_p, owned_g, owned_d, mu[0], nu[0], new_count,
+                    gnorm)
+            with jax.named_scope("step/sync"):
+                new_flat = z_ag(new_op, sid, f32.shape)
+                new_params = unravel(new_flat.astype(flat_p.dtype))
             om = {"grad_norm": gnorm, "lr": lr}
             if telemetry:
                 from .striped import rs_conservation_gap
@@ -475,7 +485,8 @@ def make_train_step(api, opt, mesh, mode: str = "gspmd", fsdp: bool = True,
     def _step(params, opt_state, batch, schedule_id=None):
         out = synced_loss_and_grads(params, batch, schedule_id)
         loss, aux, grads = out[:3]
-        new_params, new_state, om = opt.apply(params, grads, opt_state)
+        with jax.named_scope("step/optimizer"):
+            new_params, new_state, om = opt.apply(params, grads, opt_state)
         metrics = {"loss": loss, **om, **aux}
         if telemetry:
             metrics.update(out[3])

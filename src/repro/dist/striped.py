@@ -46,7 +46,7 @@ import jax.numpy as jnp
 from ..core.collectives import (StripedCollectiveSpec, REDUCE,
                                 striped_tables)
 from .tree_allreduce import (_FLOATS, _REDUCE_WIRE, _axis_arg, _gather,
-                             _note_trace, _rows_of, _rows_out, _scope,
+                             _note_trace, _rows_of, _rows_out,
                              _send, resolve_codec)
 
 
@@ -74,9 +74,7 @@ def _run_wave(state, bw, idx, axis, rs_wire, ag_wire):
     Non-senders compute a (discarded) payload and non-receivers carry a
     zero-length mask, so the whole wave is branch-free; ``ppermute``
     hands devices nobody sent to a zero payload, which the circular mask
-    drops anyway.  Split out of :func:`_run_waves` so the instrumented
-    wave-by-wave executor (:mod:`repro.telemetry.timing`) can jit and
-    time exactly the production wave body."""
+    drops anyway.  The loop body of :func:`_run_waves`."""
     k, mrow = state.shape
     pos = jnp.arange(mrow)
     rows_iota = jnp.arange(k)
@@ -107,7 +105,7 @@ def _run_waves(state, waves, idx, axis, rs_wire, ag_wire):
     """Execute bound striped waves on the (k, mrow) state."""
     for w, bw in enumerate(waves):
         op = "rs" if bw.op == REDUCE else "ag"
-        with _scope(f"edst/t*/w{w}/{op}"):
+        with jax.named_scope(f"edst/t*/w{w}/{op}"):
             state = _run_wave(state, bw, idx, axis, rs_wire, ag_wire)
     return state
 

@@ -47,8 +47,6 @@ than the wire bytes it saves).
 """
 from __future__ import annotations
 
-import os
-from contextlib import nullcontext
 from dataclasses import dataclass
 
 import jax
@@ -205,24 +203,6 @@ _FLOATS = (jnp.float32, jnp.bfloat16, jnp.float16)
 # wave-level observability (shared by all executors)
 # ---------------------------------------------------------------------------
 
-_WAVE_SCOPES = os.environ.get("REPRO_WAVE_SCOPES", "1") != "0"
-
-
-def set_wave_scopes(enabled: bool) -> bool:
-    """Toggle the ``jax.named_scope`` wave labels (``edst/t{j}/w{w}/{op}``)
-    the executors attach so XLA device profiles attribute time to waves;
-    returns the previous setting.  Labels are pure HLO metadata -- the
-    compiled executable is identical either way -- but the toggle only
-    affects FUTURE traces, so re-jit after flipping it mid-process."""
-    global _WAVE_SCOPES
-    prev, _WAVE_SCOPES = _WAVE_SCOPES, bool(enabled)
-    return prev
-
-
-def _scope(label: str):
-    return jax.named_scope(label) if _WAVE_SCOPES else nullcontext()
-
-
 def _wave_label(w: int, wv) -> str:
     """``edst/t{tree}/w{wave}/{op}`` for a pipelined wave: the tree when
     the wave ships a single chunk row, ``t*`` for merged waves."""
@@ -318,7 +298,7 @@ def run_tree_program(c, tree: TreeProgram, n: int, axis,
     # exactly once, deepest level first, so parents accumulate complete
     # subtree sums before forwarding
     for w, perm in enumerate(tree.reduce_rounds):
-        with _scope(f"edst/t{scope_tree}/w{w}/reduce"):
+        with jax.named_scope(f"edst/t{scope_tree}/w{w}/reduce"):
             c = c + _send(c, axis, perm, wire)
     # broadcast: the root's total overwrites down the levels.  Quantized,
     # the total is packed ONCE and the int8 wire forwards verbatim.
@@ -329,13 +309,13 @@ def run_tree_program(c, tree: TreeProgram, n: int, axis,
         packed = _pack_wire32(c)
         for w, (perm, table) in enumerate(zip(tree.bcast_rounds,
                                               tree.bcast_dst)):
-            with _scope(f"edst/t{scope_tree}/w{base + w}/bcast"):
+            with jax.named_scope(f"edst/t{scope_tree}/w{base + w}/bcast"):
                 recv = jax.lax.ppermute(packed, axis, list(perm))
                 packed = jnp.where(jnp.asarray(table)[idx], recv, packed)
         return _unpack_wire32(packed, c.dtype, c.shape[0])
     for w, (perm, table) in enumerate(zip(tree.bcast_rounds,
                                           tree.bcast_dst)):
-        with _scope(f"edst/t{scope_tree}/w{base + w}/bcast"):
+        with jax.named_scope(f"edst/t{scope_tree}/w{base + w}/bcast"):
             recv = jax.lax.ppermute(c, axis, list(perm))
             c = jnp.where(jnp.asarray(table)[idx], recv, c)
     return c
@@ -445,7 +425,7 @@ def fused_tree_allreduce(x, spec: FusedAllreduceSpec, quantize: bool = False,
     # devices nobody sent to); multi-row waves scatter the arrival to a
     # one-hot (k, m) contribution first.
     for w, rnd in enumerate(spec.reduce_rounds):
-        with _scope(f"edst/t*/w{w}/reduce"):
+        with jax.named_scope(f"edst/t*/w{w}/reduce"):
             recv, flag, recv_rows = _fused_send(chunks, rnd, idx, axis,
                                                 r_wire)
             if k == 1:
@@ -471,7 +451,7 @@ def fused_tree_allreduce(x, spec: FusedAllreduceSpec, quantize: bool = False,
         chunks = _pack_wire32(chunks)
     base = len(spec.reduce_rounds)
     for w, rnd in enumerate(spec.bcast_rounds):
-        with _scope(f"edst/t*/w{base + w}/bcast"):
+        with jax.named_scope(f"edst/t*/w{base + w}/bcast"):
             recv, flag, recv_rows = _fused_send(chunks, rnd, idx, axis)
             if k == 1:
                 chunks = jnp.where(flag, recv, chunks)
@@ -622,7 +602,7 @@ def pipelined_tree_allreduce(x, spec: PipelinedAllreduceSpec,
             rows = _q8_unrolled(rows, spec, idx, axis, codec)
         else:
             for w, wv in enumerate(spec.waves):
-                with _scope(_wave_label(w, wv)):
+                with jax.named_scope(_wave_label(w, wv)):
                     recv = jax.lax.ppermute(_select_payload(rows, wv, idx),
                                             axis, list(wv.perm))
                     rows = _apply_wave(rows, wv, recv, idx)
@@ -643,7 +623,7 @@ def _q8_unrolled(rows, spec, idx, axis, codec):
     r_wire = _REDUCE_WIRE[codec]
     bnd = spec.q8_boundary
     for w, wv in enumerate(spec.q8_waves[:bnd]):
-        with _scope(_wave_label(w, wv)):
+        with jax.named_scope(_wave_label(w, wv)):
             payload = _select_payload(rows, wv, idx)
             if r_wire == "q8" and payload.dtype in _FLOATS:
                 wire = jax.lax.ppermute(q8_pack(payload), axis,
@@ -657,7 +637,7 @@ def _q8_unrolled(rows, spec, idx, axis, codec):
             rows = _apply_wave(rows, wv, recv, idx)
     if bnd == len(spec.q8_waves) or dtype not in _FLOATS:
         for w, wv in enumerate(spec.q8_waves[bnd:]):
-            with _scope(_wave_label(bnd + w, wv)):
+            with jax.named_scope(_wave_label(bnd + w, wv)):
                 recv = jax.lax.ppermute(_select_payload(rows, wv, idx),
                                         axis, list(wv.perm))
                 rows = _apply_wave(rows, wv, recv, idx)
@@ -668,7 +648,7 @@ def _q8_unrolled(rows, spec, idx, axis, codec):
     else:
         packed = list(_pack_wire32(jnp.stack(rows)))
     for w, wv in enumerate(spec.q8_waves[bnd:]):
-        with _scope(_wave_label(bnd + w, wv)):
+        with jax.named_scope(_wave_label(bnd + w, wv)):
             recv = jax.lax.ppermute(_select_payload(packed, wv, idx),
                                     axis, list(wv.perm))
             for j in range(len(packed)):
@@ -716,7 +696,7 @@ def _scanned(rows, spec, idx, axis, segments, msub, codec, dtype):
     def body(t, carry):
         st, pst = carry
         for w, wv in enumerate(waves):
-            with _scope(_wave_label(w, wv)):
+            with jax.named_scope(_wave_label(w, wv)):
                 seg = t - stage[w]
                 valid = (seg >= 0) & (seg < segments)
                 segc = jnp.clip(seg, 0, segments - 1)

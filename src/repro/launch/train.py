@@ -92,9 +92,11 @@ def main(argv=None):
                          "fault-runtime entry table is rendered)")
     ap.add_argument("--profile-dir", default=None,
                     help="capture a JAX profiler trace of the training "
-                         "loop into DIR; the executors' edst/t*/w*/op "
-                         "named scopes label every sync wave in the "
-                         "timeline")
+                         "loop into DIR: host spans train/{input,dispatch,"
+                         "readback,checkpoint,recovery} inside one "
+                         "'train' step span each, and device ops labelled "
+                         "by the step's step/*, model/* and "
+                         "edst/t*/w*/op named scopes")
     ap.add_argument("--metrics-out", default=None,
                     help="dump the telemetry metrics registry (JSON) at "
                          "the end of the run")
@@ -201,62 +203,81 @@ def main(argv=None):
         t0 = time.time()
         losses = []
         step = start
+        # host spans, on the profiler's clock with the device ops: each
+        # idle gap of a --profile-dir trace falls in one of them
+        ann = jax.profiler.TraceAnnotation
         while step < args.steps:
-            batch = {"tokens": jnp.asarray(stream.batch(step))}
-            if ctrl is not None:
-                snapshot = (params, opt_state)
-                t1 = time.time()
-                params, opt_state, metrics = jstep(
-                    params, opt_state, batch, jnp.int32(ctrl.schedule_id))
-                loss = float(metrics["loss"])   # blocks: dt is the real step
-                report = monitor.check(
-                    step, step_time=time.time() - t1,
-                    checksum_dev=float(metrics.get("sync_dev", 0.0)))
-                dec = ctrl.observe(report)
-                if dec.action == "rescale" and ctrl.state == "stalled":
-                    # a lost node needs a NEW process mesh: checkpoint and
-                    # hand off to repro.launch.elastic on the survivors
-                    params, opt_state = snapshot
-                    if args.ckpt_dir:
-                        _save(args, step, params, opt_state, zmap)
-                    print(f"[train] node loss at step {step} "
-                          f"({dec.detail.get('nodes')}); checkpoint saved "
-                          "-- relaunch on the surviving mesh "
-                          "(repro.launch.elastic)")
-                    break
-                if dec.action != "none":
-                    # the step ran over suspect fabric: discard and redo
-                    # after recovery (flip / hot-swap / backoff)
-                    params, opt_state = snapshot
-                    print(f"[train] step {step}: {dec.action} "
-                          f"(schedule {dec.schedule_id}) {dec.detail}")
-                    if dec.runtime_changed:
-                        from repro.dist.health import HealthMonitor
-                        step_fn = make_train_step(
-                            api, opt, mesh, mode=args.sync,
-                            quantize=args.quantize_grads,
-                            engine=args.edst_engine,
-                            fault_runtime=ctrl.runtime, telemetry=True)
-                        jstep = jax.jit(step_fn,
-                                        out_shardings=out_shardings)
-                        monitor = HealthMonitor(mesh, ctrl.runtime,
-                                                straggler=monitor.straggler)
-                    if dec.backoff_s:
-                        time.sleep(dec.backoff_s)
-                    continue
-            else:
-                params, opt_state, metrics = jstep(params, opt_state, batch)
-                loss = float(metrics["loss"])
-            losses.append(loss)
-            steps_total.inc(mode=args.sync)
-            if step % args.log_every == 0 or step == args.steps - 1:
-                dt = time.time() - t0
-                print(f"[train] step {step:5d} loss {losses[-1]:.4f} "
-                      f"gnorm {float(metrics['grad_norm']):.6g} "
-                      f"lr {float(metrics['lr']):.2e} ({dt:.3f}s)")
-            if args.ckpt_dir and (step + 1) % args.ckpt_every == 0:
-                _save(args, step + 1, params, opt_state, zmap)
-            step += 1
+            with jax.profiler.StepTraceAnnotation("train", step_num=step):
+                with ann("train/input"):
+                    batch = {"tokens": jnp.asarray(stream.batch(step))}
+                if ctrl is not None:
+                    snapshot = (params, opt_state)
+                    t1 = time.time()
+                    with ann("train/dispatch"):
+                        params, opt_state, metrics = jstep(
+                            params, opt_state, batch,
+                            jnp.int32(ctrl.schedule_id))
+                    with ann("train/readback"):
+                        # blocks: dt is the real step
+                        loss = float(metrics["loss"])
+                    with ann("train/recovery"):
+                        report = monitor.check(
+                            step, step_time=time.time() - t1,
+                            checksum_dev=float(metrics.get("sync_dev", 0.0)))
+                        dec = ctrl.observe(report)
+                    if dec.action == "rescale" and ctrl.state == "stalled":
+                        # a lost node needs a NEW process mesh: checkpoint
+                        # and hand off to repro.launch.elastic on the
+                        # survivors
+                        params, opt_state = snapshot
+                        if args.ckpt_dir:
+                            with ann("train/checkpoint"):
+                                _save(args, step, params, opt_state, zmap)
+                        print(f"[train] node loss at step {step} "
+                              f"({dec.detail.get('nodes')}); checkpoint "
+                              "saved -- relaunch on the surviving mesh "
+                              "(repro.launch.elastic)")
+                        break
+                    if dec.action != "none":
+                        # the step ran over suspect fabric: discard and
+                        # redo after recovery (flip / hot-swap / backoff)
+                        params, opt_state = snapshot
+                        print(f"[train] step {step}: {dec.action} "
+                              f"(schedule {dec.schedule_id}) {dec.detail}")
+                        with ann("train/recovery"):
+                            if dec.runtime_changed:
+                                from repro.dist.health import HealthMonitor
+                                step_fn = make_train_step(
+                                    api, opt, mesh, mode=args.sync,
+                                    quantize=args.quantize_grads,
+                                    engine=args.edst_engine,
+                                    fault_runtime=ctrl.runtime,
+                                    telemetry=True)
+                                jstep = jax.jit(step_fn,
+                                                out_shardings=out_shardings)
+                                monitor = HealthMonitor(
+                                    mesh, ctrl.runtime,
+                                    straggler=monitor.straggler)
+                            if dec.backoff_s:
+                                time.sleep(dec.backoff_s)
+                        continue
+                else:
+                    with ann("train/dispatch"):
+                        params, opt_state, metrics = jstep(params, opt_state,
+                                                           batch)
+                    with ann("train/readback"):
+                        loss = float(metrics["loss"])
+                losses.append(loss)
+                steps_total.inc(mode=args.sync)
+                if step % args.log_every == 0 or step == args.steps - 1:
+                    dt = time.time() - t0
+                    print(f"[train] step {step:5d} loss {losses[-1]:.4f} "
+                          f"gnorm {float(metrics['grad_norm']):.6g} "
+                          f"lr {float(metrics['lr']):.2e} ({dt:.3f}s)")
+                if args.ckpt_dir and (step + 1) % args.ckpt_every == 0:
+                    with ann("train/checkpoint"):
+                        _save(args, step + 1, params, opt_state, zmap)
+                step += 1
         if args.profile_dir:
             jax.profiler.stop_trace()
             print(f"[train] profiler trace -> {args.profile_dir}")

@@ -10,10 +10,29 @@ Parameters are plain dicts; every ``init_*`` returns ``(params, axes)`` where
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+
+# ---------------------------------------------------------------------------
+# sublayer scopes
+# ---------------------------------------------------------------------------
+
+def sublayer(name: str):
+    """Trace the decorated layer under ``jax.named_scope(name)``, so every
+    op it adds (its backward and recomputation included) names the
+    sublayer in the compiled program's metadata.  Metadata only: the
+    compiled program is the same without it."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return scoped
+    return wrap
 
 
 # ---------------------------------------------------------------------------
@@ -218,6 +237,7 @@ def _headwise_rms(x, scale, eps=1e-6):
     return (x * scale).astype(dt)
 
 
+@sublayer("model/attention")
 def attention(p, cfg: AttnCfg, x, positions, *, kv_cache=None, cache_len=None,
               cache_write_idx=None, cache_positions=None,
               kv_x=None, kv_positions=None, mask_mode="causal",
@@ -333,6 +353,9 @@ def sdpa(q, k, v, q_pos, kv_pos, cfg: AttnCfg, mask_mode="causal",
         """Scan kv blocks [0, n_kv_blocks) for query block qi."""
         qcur, qp = qr[:, qi], qpr[qi]
 
+        # the scope again inside the checkpointed body: its reductions
+        # lose the scopes it was called under
+        @sublayer("model/attention")
         def step(carry, inputs):
             m, l, acc = carry
             kblk, vblk, kp = inputs
@@ -400,6 +423,7 @@ def init_glu_mlp(key, d, f, kind="swiglu"):
     return p, a
 
 
+@sublayer("model/mlp")
 def glu_mlp(p, x, kind="swiglu"):
     act = jax.nn.silu if kind == "swiglu" else jax.nn.gelu
     g = jnp.einsum("bsd,df->bsf", x, p["wi_gate"].astype(x.dtype))
@@ -431,10 +455,12 @@ def init_embedding(key, vocab_padded, d):
             {"table": ("vocab", "embed")})
 
 
+@sublayer("model/embed")
 def embed(p, tokens, dtype=jnp.bfloat16):
     return batch_hint(p["table"].astype(dtype)[tokens])
 
 
+@sublayer("model/head")
 def unembed(p, x, vocab: int):
     """Logits against the (tied) embedding table; padded slots masked."""
     logits = jnp.einsum("bsd,vd->bsv", x, p["table"].astype(x.dtype))
@@ -445,6 +471,7 @@ def unembed(p, x, vocab: int):
     return logits
 
 
+@sublayer("model/head")
 def chunked_unembed_xent(embed_p, x, labels, vocab: int, chunk: int = 512,
                          z_loss=1e-4):
     """Cross-entropy over tied-embedding logits, computed (and re-computed in
@@ -463,6 +490,7 @@ def chunked_unembed_xent(embed_p, x, labels, vocab: int, chunk: int = 512,
     lr = labels.reshape(b, nch, c).swapaxes(0, 1)
 
     @jax.checkpoint
+    @sublayer("model/head")     # inside: the reductions lose outer scopes
     def step(acc, inp):
         xc, lc = inp
         logits = unembed(embed_p, xc, vocab).astype(jnp.float32)

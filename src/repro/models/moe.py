@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .layers import ninit
+from .layers import ninit, sublayer
 
 
 @dataclasses.dataclass(frozen=True)
@@ -59,6 +59,7 @@ def init_moe(key, cfg: MoECfg):
     return p, a
 
 
+@sublayer("model/mlp")
 def moe_layer(p, cfg: MoECfg, x):
     """x: (B, S, d) -> (out (B, S, d), aux_losses dict)."""
     b, s, d = x.shape

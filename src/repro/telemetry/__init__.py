@@ -9,23 +9,26 @@ Three pillars (see ``src/repro/dist/README.md`` -> "Observability"):
   * :mod:`repro.telemetry.trace`   -- Chrome-trace-event (Perfetto)
     export of any compiled wave program: spans per message, lanes per
     device or tree, flow events along the verifier's happens-before DAG,
-    predicted (CostModel) or measured timings;
-  * :mod:`repro.telemetry.timing`  -- the wave-by-wave instrumented
-    executor: per-wave measured durations, residuals against the
-    CostModel's predictions, and calibration fitting.
+    predicted (CostModel) timings, or per-wave times read from a device
+    profile.
+
+The third is the device profile itself: every executor runs each wave
+under ``jax.named_scope("edst/t{tree}/w{wave}/{op}")`` and the train
+step its phases under ``step/*`` and ``model/*`` scopes, so a profiler
+trace of the compiled program attributes each op's time.
 
 ``metrics`` is pure stdlib and imported eagerly; ``trace`` needs NumPy
-only; ``timing`` imports JAX and is loaded lazily.
+only and is loaded lazily.
 """
 from __future__ import annotations
 
 from . import metrics  # noqa: F401  (stdlib-only, always safe)
 
-__all__ = ("metrics", "trace", "timing")
+__all__ = ("metrics", "trace")
 
 
 def __getattr__(name):
-    if name in ("trace", "timing"):
+    if name == "trace":
         import importlib
         return importlib.import_module(f".{name}", __name__)
     raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

@@ -20,8 +20,8 @@ whole fault-runtime entry tables -- as Chrome Trace Event Format JSON
 Timings are *predicted* by default -- each wave lasts ``alpha +
 wire_bytes / link_bw`` under the (deterministic) default
 :class:`repro.core.collectives.CostModel`, so traces are byte-stable and
-golden-diffable -- or *measured* when per-wave durations from
-:mod:`repro.telemetry.timing` are passed via ``wave_times``.
+golden-diffable -- or *measured* when per-wave durations (read from a
+device profile) are passed via ``wave_times``.
 
 Pure NumPy + stdlib (the verifier's scanners do the message recovery):
 importable and runnable without JAX, like the verify CLI.
@@ -350,10 +350,6 @@ def main(argv=None) -> int:
     ap.add_argument("--validate", action="store_true",
                     help="schema-validate every written trace (exit 1 on "
                          "any violation)")
-    ap.add_argument("--measured", action="store_true",
-                    help="time each wave on fake host devices (imports "
-                         "JAX; pipelined/striped only) instead of using "
-                         "CostModel predictions")
     args = ap.parse_args(argv)
 
     from ..analysis.verify import _compile_specs, _schedule_for
@@ -370,17 +366,8 @@ def main(argv=None) -> int:
             if isinstance(spec, str):
                 print(f"[trace] {label}/{engine}: SKIP ({spec})")
                 continue
-            wave_times = None
-            if args.measured:
-                if engine not in ("pipelined", "striped"):
-                    print(f"[trace] {label}/{engine}: SKIP measured mode "
-                          "(pipelined/striped only)")
-                    continue
-                from .timing import measured_wave_times
-                wave_times = measured_wave_times(spec, nbytes=args.nbytes)
             trace = trace_spec(spec, nbytes=args.nbytes, lane=args.lane,
-                               label=f"{label}/{engine}",
-                               wave_times=wave_times)
+                               label=f"{label}/{engine}")
             path = _out_path(args, label, engine)
             write_trace(path, trace)
             nspans = sum(1 for e in trace["traceEvents"] if e["ph"] == "X")

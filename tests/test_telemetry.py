@@ -342,3 +342,122 @@ print('EDST_TELEMETRY_OK')
 def test_telemetry_dict_edst_wire_gauge_tracks_schedule(subproc):
     out = subproc(EDST_TELEMETRY_CODE, 16)
     assert "EDST_TELEMETRY_OK" in out
+
+
+# ---------------------------------------------------------------------------
+# named scopes of the train step (what a device profile's ops carry)
+# ---------------------------------------------------------------------------
+
+def _op_names(hlo_text: str) -> set:
+    import re
+    return set(re.findall(r'op_name="([^"]*)"', hlo_text))
+
+
+@pytest.fixture(scope="module")
+def step_op_names():
+    """op_names of the reduced smollm step compiled on one device, as
+    the train launcher jits it."""
+    from repro.dist.sharding import train_state_shardings
+    from repro.dist.steps import make_train_step
+    from repro.models.api import build
+    from repro.optim import AdamW, cosine_schedule
+    cfg = configs.get("smollm-135m").reduced()
+    api = build(cfg)
+    mesh = make_mesh((1, 1), ("data", "model"))
+    opt = AdamW(cosine_schedule(1e-3, 5, 50))
+    params, axes = api.init(jax.random.PRNGKey(0))
+    opt_state = opt.init(params)
+    shards = train_state_shardings(axes, params, mesh)
+    params, opt_state = jax.device_put((params, opt_state), shards)
+    batch = {"tokens": jnp.zeros((4, 33), jnp.int32)}
+    with jax.set_mesh(mesh):
+        jstep = jax.jit(make_train_step(api, opt, mesh, mode="gspmd"),
+                        out_shardings=(*shards, None))
+        text = jstep.lower(params, opt_state, batch).compile().as_text()
+    return _op_names(text)
+
+
+@pytest.mark.parametrize("what, holds", [
+    ("forward", lambda n: "jvp(step/model)" in n and "transpose(" not in n),
+    ("backward", lambda n: "transpose(jvp(step/model))" in n),
+    ("recomputation", lambda n: "rematted_computation" in n),
+    ("optimizer", lambda n: "step/optimizer" in n),
+    ("embed", lambda n: "model/embed/" in n),
+    ("attention", lambda n: "model/attention/" in n),
+    ("mlp", lambda n: "model/mlp/" in n),
+    ("head", lambda n: "model/head/" in n),
+])
+def test_step_scopes_reach_the_compiled_program(step_op_names, what, holds):
+    """Each phase and sublayer of the step names some op of the compiled
+    program; no op of the one-device step is under a sync scope."""
+    assert any(holds(n) for n in step_op_names), what
+    assert not any("step/sync" in n or "edst/" in n for n in step_op_names)
+
+
+EDST_SCOPES_CODE = r"""
+import re
+import jax, jax.numpy as jnp
+from repro import configs
+from repro.core.collectives import wave_wire_bytes
+from repro.dist.sharding import train_state_shardings
+from repro.dist.steps import edst_spec_for_mesh, make_train_step
+from repro.launch.mesh import make_mesh
+from repro.models.api import build
+from repro.optim import AdamW, cosine_schedule
+from repro.telemetry import metrics as tm
+
+cfg = configs.get('smollm-135m').reduced()
+api = build(cfg)
+mesh = make_mesh((4, 1), ('data', 'model'))
+opt = AdamW(cosine_schedule(1e-3, 5, 50))
+params, axes = api.init(jax.random.PRNGKey(0))
+opt_state = opt.init(params)
+shards = train_state_shardings(axes, params, mesh)
+params, opt_state = jax.device_put((params, opt_state), shards)
+batch = {'tokens': jnp.zeros((8, 33), jnp.int32)}
+with jax.set_mesh(mesh):
+    jstep = jax.jit(make_train_step(api, opt, mesh, mode='edst'),
+                    out_shardings=(*shards, None))
+    text = jstep.lower(params, opt_state, batch).compile().as_text()
+names = set(re.findall(r'op_name="([^"]*)"', text))
+waves = [n for n in names if 'edst/' in n]
+assert waves, 'no op under an edst/ wave scope'
+outside = [n for n in waves if 'step/sync' not in n]
+assert not outside, outside[:5]
+assert any('step/optimizer' in n for n in names)
+assert any('transpose(jvp(step/model))' in n for n in names)
+spec = edst_spec_for_mesh((4, 1), ('data', 'model'))
+flat = sum(int(x.size) for x in jax.tree.leaves(params))
+want = sum(wave_wire_bytes(spec, flat * 4, 4))
+got = tm.REGISTRY.get('edst_wire_bytes').value(engine='pipelined')
+assert got == want, (got, want)
+print('EDST_SCOPES_OK', len(waves), got)
+"""
+
+
+def test_step_scopes_edst_waves_under_sync(subproc):
+    """On four devices every EDST wave op is also under ``step/sync``,
+    and the program's wire-bytes gauge is the schedule's sum."""
+    out = subproc(EDST_SCOPES_CODE, 4)
+    assert "EDST_SCOPES_OK" in out
+
+
+def test_train_profile_has_host_spans(tmp_path):
+    """``train.py --profile-dir`` puts each step and its input, dispatch,
+    read-back and checkpoint on the profiler's host plane."""
+    from jax.profiler import ProfileData
+    from repro.launch.train import main as train_main
+    prof = tmp_path / "prof"
+    train_main(["--arch", "smollm-135m", "--reduced", "--steps", "2",
+                "--batch", "2", "--seq", "16", "--mesh", "1,1",
+                "--log-every", "1", "--ckpt-dir", str(tmp_path / "ck"),
+                "--ckpt-every", "1", "--profile-dir", str(prof)])
+    path = next(prof.rglob("*.xplane.pb"))
+    names = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name == "/host:CPU":
+            names += [ev.name for line in plane.lines for ev in line.events]
+    for span in ("train/input", "train/dispatch", "train/readback",
+                 "train/checkpoint"):
+        assert names.count(span) == 2, (span, names.count(span))
+    assert names.count("train") == 2
