@@ -389,7 +389,12 @@ def make_train_step(api, opt, mesh, mode: str = "gspmd", fsdp: bool = True,
                         flat = tree_allreduce(flat, tree_spec,
                                               quantize=quantize,
                                               segments=segments)
-                    grads = unravel(flat / ndp)
+                    # divide leaf by leaf: the summed vector then feeds
+                    # the unravel's slices as the engine returns it, and
+                    # XLA keeps fewer gradient-sized copies alive at once
+                    # (the same values wherever a leaf has the vector's
+                    # dtype, or the divisor is a power of two)
+                    grads = jax.tree.map(lambda g: g / ndp, unravel(flat))
                 if telemetry:
                     from .health import (payload_checksum,
                                          replication_divergence)

@@ -198,6 +198,10 @@ _REDUCE_WIRE = {"full": "q8", "hybrid": "bf16", "bcast": None, "off": None}
 
 _FLOATS = (jnp.float32, jnp.bfloat16, jnp.float16)
 
+# one (8, 128) tile of 32-bit words, the unit of the TPU's array layout
+_TILE_BYTES = 4096
+_LANES = 128
+
 
 # ---------------------------------------------------------------------------
 # wave-level observability (shared by all executors)
@@ -213,16 +217,18 @@ def _wave_label(w: int, wv) -> str:
     return f"edst/{tree}/w{w}/{op}"
 
 
-def _note_trace(engine: str, spec, x, codec=None, fractions=None) -> None:
+def _note_trace(engine: str, spec, x, codec=None, fractions=None,
+                segments=None) -> None:
     """Executor-entry metrics hook.  Inside ``jit`` this Python runs at
     trace time only, so it counts compiled program traces (the retrace
-    detector), not steps -- and costs nothing per step."""
+    detector), not steps -- and costs nothing per step.  ``segments``
+    is the pipelined engine's compiled segment count."""
     try:
         itemsize = jnp.dtype(x.dtype).itemsize
         wires = wave_wire_bytes(spec, x.size * itemsize, itemsize, fractions)
         _metrics.note_program(engine, getattr(spec, "key", None) or spec,
                               waves=len(wires), wire_bytes=sum(wires),
-                              codec=codec)
+                              codec=codec, segments=segments)
     except Exception:       # pragma: no cover - telemetry never breaks a step
         pass
 
@@ -502,31 +508,24 @@ def _select_payload(rows, wv, idx):
     return payload
 
 
-def _apply_wave(rows, wv, recv, idx, valid=None):
+def _apply_wave(rows, wv, recv, idx):
     """Land one wave's arrival: accumulate into reduce destinations,
     overwrite broadcast destinations, leave everyone else untouched.
-    ``wv.sole_add`` waves skip masking (zero payload on non-destinations);
-    ``valid`` gates fill/drain steps of the pipelined scan."""
+    ``wv.sole_add`` waves skip masking (zero payload on non-destinations).
+    Rows the wave does not touch may be ``None``."""
     zero = jnp.zeros((), recv.dtype)
     for j in range(len(rows)):
         rf, bf = wv.reduce_flag[j], wv.bcast_flag[j]
         if not (rf.any() or bf.any()):
             continue
-        if wv.sole_add == j and valid is None:
+        if wv.sole_add == j:
             rows[j] = _acc(rows[j], recv)
             continue
         base = rows[j]
         if rf.any():
-            mask = _gather(rf, idx) if valid is None \
-                else _gather(rf, idx) & valid
-            if wv.sole_add == j:
-                base = _acc(base, jnp.where(valid, recv, zero))
-            else:
-                base = _acc(base, jnp.where(mask, recv, zero))
+            base = _acc(base, jnp.where(_gather(rf, idx), recv, zero))
         if bf.any():
-            mask = _gather(bf, idx) if valid is None \
-                else _gather(bf, idx) & valid
-            base = jnp.where(mask, recv, base)
+            base = jnp.where(_gather(bf, idx), recv, base)
         rows[j] = base
     return rows
 
@@ -563,9 +562,10 @@ def pipelined_tree_allreduce(x, spec: PipelinedAllreduceSpec,
     overhead); S>1 runs a ``fori_loop`` over ``waves + S - 1`` steps in
     which wave w moves segment ``t - w`` -- steady state keeps every
     tree edge busy and the HLO holds each wave's collective exactly
-    once, whatever S is.  ``"auto"`` asks the backend-calibrated cost
-    model (:func:`auto_segments`).  ``quantize``/``codec`` select the
-    int8 wire (see module docstring).
+    once, whatever S is; its segments are whole tiles of a
+    segment-major carry (see :func:`_scanned`).  ``"auto"`` asks the
+    backend-calibrated cost model (:func:`auto_segments`).
+    ``quantize``/``codec`` select the int8 wire (see module docstring).
     """
     if spec.k == 0 or x.size == 0:
         return x
@@ -591,13 +591,11 @@ def pipelined_tree_allreduce(x, spec: PipelinedAllreduceSpec,
     if segments == "auto" or segments is None:
         segments = auto_segments(spec, mrow, dtype.itemsize)
     segments = max(1, min(int(segments), mrow))
-    msub = -(-mrow // segments)
-    mrow = msub * segments
     _note_trace("pipelined", spec, x, codec=codec if quantize else None,
-                fractions=fractions)
-    rows = _rows_of(flat, k, sizes, mrow)
+                fractions=fractions, segments=segments)
 
     if segments == 1:
+        rows = _rows_of(flat, k, sizes, mrow)
         if quantize:
             rows = _q8_unrolled(rows, spec, idx, axis, codec)
         else:
@@ -607,8 +605,10 @@ def pipelined_tree_allreduce(x, spec: PipelinedAllreduceSpec,
                                             axis, list(wv.perm))
                     rows = _apply_wave(rows, wv, recv, idx)
     else:
-        rows = _scanned(rows, spec, idx, axis, segments, msub,
-                        codec if quantize else None, dtype)
+        msub = _whole_tiles(-(-mrow // segments), dtype.itemsize)
+        rows = _scanned(_rows_of(flat, k, sizes, (segments + 1) * msub),
+                        spec, idx, axis, segments, msub,
+                        codec if quantize else None)
 
     out = _rows_out(rows, sizes, flat.size)
     return out.reshape(shape).astype(dtype)
@@ -660,16 +660,41 @@ def _q8_unrolled(rows, spec, idx, axis, codec):
     return list(_unpack_wire32(jnp.stack(packed), dtype, mrow))
 
 
-def _scanned(rows, spec, idx, axis, segments, msub, codec, dtype):
+def _whole_tiles(n: int, itemsize: int) -> int:
+    """``n`` elements rounded up to whole (8, 128) tiles of 32-bit words
+    (the (16, 128) bf16 and (32, 128) int8 tiles hold the same bytes)."""
+    quantum = _TILE_BYTES // itemsize
+    return -(-n // quantum) * quantum
+
+
+def _scanned(rows, spec, idx, axis, segments, msub, codec):
     """S>1: software-pipeline the wave program with a ``fori_loop`` over
-    the step index.  The carry holds the ``(k, S, msub)`` segmented state
-    (plus the packed broadcast state when quantized); the body issues
-    every wave once on segment ``t - stage(w)``, so the compiled HLO
-    holds one collective per wave however many segments stream through.
-    Out-of-range segments clamp and their arrivals are masked off, which
-    makes the fill/drain steps no-ops for inactive waves."""
+    the step index; the body issues every wave once on segment
+    ``t - stage(w)``, so the compiled HLO holds one collective per wave
+    however many segments stream through.
+
+    The carry is segment-major: ``(k, S + 1, msub // 128, 128)``, where
+    ``msub`` is a whole number of tiles, so one segment is a run of whole
+    (8, 128) tiles that a read or write moves without touching its
+    neighbours, and a ``(S + 1) * msub`` chunk row reshapes into it as
+    is.  Segment S is a spare: a wave whose segment lies outside
+    ``[0, S)`` (pipeline fill and drain) reads and writes the spare
+    instead, and no output reads the spare, so no arrival needs
+    masking.  The int8 carry of the quantized scan has the same layout,
+    each row holding the ``(msub + 4,)`` wire in whole int8 tiles.
+
+    Each step reads every wave's segment (and the pack stage's) from the
+    carry it received, then sends and lands every wave, then writes the
+    segments back.  That is exact: wave w touches only segment
+    ``t - stage(w)``, the stages are distinct, and the pack stage reads
+    ``st`` at ``t - boundary``, which no reduce wave writes in the same
+    step; the spare absorbs every out-of-range access.  With no data
+    path from one wave's write to another's read, the waves' collectives
+    are free to run at once on their disjoint links."""
     k = len(rows)
-    st = jnp.stack(rows).reshape(k, segments, msub)
+    dtype = rows[0].dtype
+    tile = (msub // _LANES, _LANES)
+    st = jnp.stack(rows).reshape(k, segments + 1, *tile)
     waves = spec.waves if codec is None else spec.q8_waves
     boundary = len(waves) if codec is None else spec.q8_boundary
     # quantized scans insert a pack pseudo-stage at the phase boundary,
@@ -677,61 +702,84 @@ def _scanned(rows, spec, idx, axis, segments, msub, codec, dtype):
     stage = [w if (codec is None or w < boundary) else w + 1
              for w in range(len(waves))]
     nsteps = (len(waves) if codec is None else len(waves) + 1) + segments - 1
+    bcast = [codec is not None and w >= boundary for w in range(len(waves))]
+    used = [sorted(set(wv.rows) | {j for j in range(k)
+                                   if wv.reduce_flag[j].any()
+                                   or wv.bcast_flag[j].any()})
+            for wv in waves]
     pst = None
     if codec is not None:
+        wire = _whole_tiles(msub + 4, 1)
         # the packed carry varies over the manual axes as ``st`` does
-        pst = jnp.zeros((k, segments, msub + 4), jnp.int8)
+        pst = jnp.zeros((k, segments + 1, wire // _LANES, _LANES), jnp.int8)
         vma = tuple(jax.typeof(st).vma)
         if vma:
             pst = jax.lax.pcast(pst, vma, to="varying")
 
-    def seg_slice(arr, j, seg):
-        return jax.lax.dynamic_slice(
-            arr, (j, seg, 0), (1, 1, arr.shape[-1])).reshape(-1)
+    def seg_read(arr, j, seg, n):
+        flat = jax.lax.dynamic_slice(
+            arr, (j, seg, 0, 0), (1, 1) + arr.shape[2:]).reshape(-1)
+        return flat if n == flat.shape[0] else flat[:n]
 
-    def seg_update(arr, j, seg, val):
+    def seg_write(arr, j, seg, val):
+        pad = arr.shape[2] * arr.shape[3] - val.shape[0]
+        if pad:
+            val = jnp.pad(val, (0, pad))
         return jax.lax.dynamic_update_slice(
-            arr, val.reshape(1, 1, -1), (j, seg, 0))
+            arr, val.reshape((1, 1) + arr.shape[2:]), (j, seg, 0, 0))
 
     def body(t, carry):
         st, pst = carry
+
+        def at(s):      # segment t - s, or the spare (S) outside [0, S)
+            seg = t - s
+            return jnp.where((seg >= 0) & (seg < segments), seg, segments)
+
+        segs = [at(s) for s in stage]
+        cur = []
         for w, wv in enumerate(waves):
             with jax.named_scope(_wave_label(w, wv)):
-                seg = t - stage[w]
-                valid = (seg >= 0) & (seg < segments)
-                segc = jnp.clip(seg, 0, segments - 1)
-                bcast_wave = codec is not None and w >= boundary
-                src = pst if bcast_wave else st
-                cur = [seg_slice(src, j, segc) for j in range(k)]
-                payload = _select_payload(cur, wv, idx)
-                recv = _send(payload, axis, wv.perm,
-                             None if bcast_wave
-                             else _REDUCE_WIRE.get(codec))
-                new = _apply_wave(list(cur), wv, recv, idx, valid=valid)
-                for j in range(k):
-                    if new[j] is not cur[j]:
-                        if bcast_wave:
-                            pst = seg_update(pst, j, segc, new[j])
-                        else:
-                            st = seg_update(st, j, segc, new[j])
+                src, n = (pst, msub + 4) if bcast[w] else (st, msub)
+                cur.append([seg_read(src, j, segs[w], n) if j in used[w]
+                            else None for j in range(k)])
+        packin = None
         if codec is not None:
             # pack pseudo-stage: segment t - boundary crosses into bcast
-            seg = t - boundary
-            valid = (seg >= 0) & (seg < segments)
-            segc = jnp.clip(seg, 0, segments - 1)
+            pseg = at(boundary)
+            packin = [seg_read(st, j, pseg, msub) for j in range(k)]
+        # every read completes before the first write lands in the carry
+        cur, packin, st, pst = jax.lax.optimization_barrier(
+            (cur, packin, st, pst))
+        new = []
+        for w, wv in enumerate(waves):
+            with jax.named_scope(_wave_label(w, wv)):
+                recv = _send(_select_payload(cur[w], wv, idx), axis,
+                             wv.perm, None if bcast[w]
+                             else _REDUCE_WIRE.get(codec))
+                new.append(_apply_wave(list(cur[w]), wv, recv, idx))
+        for w, wv in enumerate(waves):
+            with jax.named_scope(_wave_label(w, wv)):
+                for j in used[w]:
+                    if new[w][j] is cur[w][j]:
+                        continue
+                    if bcast[w]:
+                        pst = seg_write(pst, j, segs[w], new[w][j])
+                    else:
+                        st = seg_write(st, j, segs[w], new[w][j])
+        if codec is not None:
             for j in range(k):
-                wire = q8_pack(seg_slice(st, j, segc))
-                old = seg_slice(pst, j, segc)
-                pst = seg_update(pst, j, segc,
-                                 jnp.where(valid, wire, old))
+                pst = seg_write(pst, j, pseg, q8_pack(packin[j]))
         return st, pst
 
     st, pst = jax.lax.fori_loop(0, nsteps, body, (st, pst))
     if codec is not None:
-        scales = jax.lax.bitcast_convert_type(
-            pst[:, :, msub:], jnp.float32).reshape(k, segments, 1)
-        st = (pst[:, :, :msub].astype(jnp.float32) * scales).astype(st.dtype)
-    return [st[j].reshape(-1) for j in range(k)]
+        # decode in the carry's tiled form: the payload is the first
+        # msub // 128 rows of each segment, the scale the next row's head
+        r = tile[0]
+        scales = jax.lax.bitcast_convert_type(pst[:, :, r, :4], jnp.float32)
+        st = (pst[:, :, :r].astype(jnp.float32)
+              * scales[..., None, None]).astype(dtype)
+    return list(st.reshape(k, -1))
 
 
 def tree_allreduce(x, spec, quantize: bool = False, segments="auto"):

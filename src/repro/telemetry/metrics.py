@@ -262,12 +262,14 @@ def counter_values(name: str) -> dict:
 
 
 def note_program(engine: str, key, waves: int, wire_bytes: int,
-                 codec: str | None = None) -> None:
+                 codec: str | None = None,
+                 segments: int | None = None) -> None:
     """Trace-time executor hook: called once per JAX trace of a compiled
     wave program (NOT per step -- inside ``jit`` the Python body runs
     only when tracing).  Counts program traces per engine, sets the
     static program gauges (wave count, total wire bytes on the fabric's
-    busiest schedule), notes the codec selection, and flags *retraces*:
+    busiest schedule, and the pipelined engine's segment count S where
+    ``segments`` is given), notes the codec selection, and flags *retraces*:
     a second trace of an identical (engine, spec key, payload, codec)
     signature means an executable that should have been cached was
     compiled again."""
@@ -286,6 +288,10 @@ def note_program(engine: str, key, waves: int, wire_bytes: int,
     gauge("edst_wire_bytes",
           "total predicted wire bytes of the most recently traced "
           "program").set(wire_bytes, engine=engine)
+    if segments is not None:
+        gauge("edst_segments",
+              "pipeline segments S of the most recently traced "
+              "program").set(segments, engine=engine)
     if codec is not None:
         counter("edst_codec_selections_total",
                 "wire codec selections at executor trace time"

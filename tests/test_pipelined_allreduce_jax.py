@@ -1,8 +1,11 @@
 """Pipelined segmented executor under shard_map on 16 fake host devices:
 psum/simulator equivalence (quantize on/off, uneven m, m < S, weighted
 fractions with a retired tree), scan-program jit-cache stability, the HLO
-contract (one collective per wave, independent of the segment count), and
-fault-runtime link-kill equality on the pipelined engine."""
+contract (one collective per wave, independent of the segment count),
+fault-runtime link-kill equality on the pipelined engine, and the
+segmented scan's exact equality with the unrolled program (on 4 or 16
+devices)."""
+import pytest
 
 CODE = r"""
 import os
@@ -233,6 +236,51 @@ for y in (y0, y1, y2):
 print("PIPELINED_FAULT_OK")
 """
 
+# run with ``DIMS = (rows, cols)`` prepended: the fabric and its mesh
+EXACT_CODE = r"""
+import numpy as np
+import jax, jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+from repro.core import topologies as topo
+from repro.core.edst_star import star_edsts
+from repro.core.collectives import (allreduce_schedule,
+                                    pipelined_spec_from_schedule)
+from repro.dist.tree_allreduce import pipelined_tree_allreduce
+from repro.launch.mesh import make_mesh
+
+n = DIMS[0] * DIMS[1]
+mesh = make_mesh(DIMS, ('a', 'b'))
+sp = topo.device_topology(DIMS)
+sched = allreduce_schedule(sp.n, star_edsts(sp).trees)
+spec = pipelined_spec_from_schedule(sched, ('a', 'b'))
+
+
+def run(x, **kw):
+    return jax.jit(jax.shard_map(
+        lambda xs: pipelined_tree_allreduce(xs.reshape(xs.shape[1:]), spec,
+                                            **kw)[None],
+        mesh=mesh, in_specs=P(('a', 'b')), out_specs=P(('a', 'b'))))(x)
+
+
+# the scan reorders no arithmetic: every element takes the same adds
+# as the S=1 unrolled program, so the f32 results are equal (``==``
+# holds for zeros of either sign)
+fractions = [None] + ([(0.7, 0.3), (1.0, 0.0)] if sched.k >= 2 else [])
+for d in (53, 3):          # uneven m; m smaller than S
+    x = jnp.asarray(np.random.RandomState(d).randn(n, d).astype(np.float32))
+    for fr in fractions:
+        y1 = run(x, segments=1, fractions=fr)
+        for S in (2, 4, 8, 64):
+            y = run(x, segments=S, fractions=fr)
+            assert bool(jnp.all(y == y1)), (DIMS, d, fr, S)
+    expect = x.sum(0)
+    for codec in ("full", "hybrid", "bcast"):
+        yq = run(x, quantize=True, segments=64, codec=codec)
+        rel = float(jnp.max(jnp.abs(yq[0] - expect) / (jnp.abs(expect) + 1)))
+        assert rel < 0.35, (DIMS, d, codec, rel)
+print("PIPELINED_EXACT_OK", sched.k)
+"""
+
 
 def test_pipelined_matches_psum_and_simulator(subproc):
     out = subproc(CODE, 16)
@@ -252,3 +300,12 @@ def test_pipelined_scan_program_jit_cache_stable(subproc):
 def test_pipelined_fault_runtime_link_kill(subproc):
     out = subproc(FAULT_CODE, 16)
     assert "PIPELINED_FAULT_OK" in out
+
+
+@pytest.mark.parametrize("dims", [(4, 1), (4, 4), (2, 8)],
+                         ids=lambda d: f"{d[0]}x{d[1]}")
+def test_pipelined_scan_equals_unrolled(subproc, dims):
+    """The segmented scan (segment-major carry, every wave's read before
+    any write) returns exactly the S=1 unrolled program's f32 sums."""
+    out = subproc(f"DIMS = {dims!r}\n" + EXACT_CODE, dims[0] * dims[1])
+    assert "PIPELINED_EXACT_OK" in out

@@ -198,6 +198,29 @@ def test_executor_note_trace_fires(monkeypatch):
     tm.reset()
 
 
+def test_note_trace_sets_segments_gauge():
+    """The pipelined engine's trace hook records the segment count S it
+    compiled beside the wire bytes; an engine that streams no segments
+    leaves the gauge unset."""
+    from repro.core import topologies as topo
+    from repro.core.collectives import (allreduce_schedule,
+                                        pipelined_spec_from_schedule)
+    from repro.core.edst_star import star_edsts
+    from repro.dist.tree_allreduce import _note_trace
+    tm.reset()
+    sp = topo.device_topology((2, 2))
+    sched = allreduce_schedule(sp.n, star_edsts(sp).trees)
+    spec = pipelined_spec_from_schedule(sched, ("data",))
+    x = jnp.ones((8,), jnp.float32)
+    _note_trace("pipelined", spec, x, segments=4)
+    _note_trace("fused", spec, x)
+    gauge = tm.REGISTRY.get("edst_segments")
+    assert gauge.value(engine="pipelined") == 4.0
+    assert gauge.value(engine="fused") is None
+    assert tm.REGISTRY.get("edst_wire_bytes").value(engine="pipelined")
+    tm.reset()
+
+
 # ---------------------------------------------------------------------------
 # recovery journal JSONL sink
 # ---------------------------------------------------------------------------
