@@ -18,6 +18,21 @@ Files, each found by the name that ``BENCHMARK.json`` gives:
   the bytes each reads and writes;
 * ``reference/<family>.py``: the plain reference;
 * ``peaks.json``: the chip's peaks by ``device_kind``.
+
+A configuration of a new family enters by these files alone.  Its file
+maps each published key beyond the dense ones of :data:`FIELDS` to the
+program's ``ArchConfig`` field under ``program_fields`` (for example
+``{"num_experts_per_tok": "top_k"}``); :func:`program_config` applies
+every key in ``reduced`` through that map, refuses any mapped key whose
+value is not the program's, and refuses a cut of scale
+(``why_reduced``) that maps to no field.  The reference and the FLOP
+count get :func:`reference_sizes`: the dense sizes as the program runs
+them, ``arch`` (every architecture key the file states, under its
+published name) and ``published`` (the published value of each key in
+``reduced``).  A reference module may name, in ``EXPERT_LEAVES``, the
+stacked leaves that carry an expert axis after the layer axis; the
+comparison then takes one norm per (layer, expert) of each, since each
+expert is a tensor of its own in the published model.
 """
 from __future__ import annotations
 
@@ -44,6 +59,10 @@ FIELDS = {"num_hidden_layers": "n_layers", "hidden_size": "d_model",
           "intermediate_size": "d_ff", "num_attention_heads": "n_heads",
           "num_key_value_heads": "n_kv", "head_dim": "head_dim",
           "vocab_size": "vocab", "rope_theta": "rope_theta"}
+# a configuration file's keys that describe the file, not the model
+META = {"name", "source", "program_arch", "family", "architectures",
+        "reduced", "published", "why_reduced", "program_departures",
+        "assumed", "deployment", "program_fields"}
 
 
 class NoChip(RuntimeError):
@@ -123,25 +142,64 @@ def peaks_for(table: dict, kind: str) -> dict:
 # the program under test
 # ---------------------------------------------------------------------------
 
+def field_value(cfg, field: str):
+    """An ArchConfig field as the program runs it (``head_dim`` 0 means
+    ``d_model / n_heads``)."""
+    return cfg.head_dim_ if field == "head_dim" else getattr(cfg, field)
+
+
+def param_shapes(api):
+    """The program's parameter tree as shapes, and its logical axes."""
+    import jax
+    box = {}
+
+    def init(key):
+        params, box["axes"] = api.init(key)
+        return params
+    key = jax.ShapeDtypeStruct((2,), np.uint32)   # a raw PRNG key's shape
+    return jax.eval_shape(init, key), box["axes"]
+
+
+def head_is_tied(cfg) -> bool:
+    """Whether the program's output head is its input embedding: no leaf
+    beside ``embed`` and outside the stacked layers is as wide as the
+    vocabulary."""
+    import jax
+    from repro.models.api import build
+    shapes, _ = param_shapes(build(cfg))
+    return not any(cfg.vocab_padded in leaf.shape
+                   for k, sub in shapes.items() if k not in ("embed", "layers")
+                   for leaf in jax.tree.leaves(sub))
+
+
 def program_config(config: dict):
     """The program's ArchConfig for a configuration file.  Keys the file
-    lists as reduced replace the program's values; every other size has
-    to equal the program's own, or the file no longer describes what
-    runs."""
+    lists as reduced replace the program's values, through
+    :data:`FIELDS` and the file's ``program_fields``; every other mapped
+    size has to equal the program's own, or the file no longer describes
+    what runs.  A cut of scale has to reach a field of the program."""
     from repro import configs
     from repro.models import layers
     cfg = configs.get(config["program_arch"])
-    cuts = {FIELDS[k]: config[k] for k in config["reduced"] if k in FIELDS}
+    fields = {**FIELDS, **config.get("program_fields", {})}
+    unknown = set(fields.values()) - {f.name for f in dataclasses.fields(cfg)}
+    if unknown:
+        raise ValueError(f"{config['name']}: program_fields names no "
+                         f"ArchConfig field {sorted(unknown)}")
+    lost = set(config["why_reduced"]) - set(fields)
+    if lost:
+        raise ValueError(f"{config['name']}: the cuts of scale {sorted(lost)} "
+                         "reach no field of the program (program_fields)")
+    cuts = {fields[k]: config[k] for k in config["reduced"] if k in fields}
     cfg = dataclasses.replace(cfg, **cuts)
-    got = {k: getattr(cfg, "head_dim_" if f == "head_dim" else f)
-           for k, f in FIELDS.items() if k in config}
+    got = {k: field_value(cfg, f) for k, f in fields.items() if k in config}
     want = {k: config[k] for k in got}
     if got != want:
         raise ValueError(f"{config['name']}: program sizes {got} != "
                          f"configuration {want}")
     eps = inspect.signature(layers.rmsnorm).parameters["eps"].default
     checks = {"rms_norm_eps": eps,
-              "tie_word_embeddings": cfg.family == "lm",
+              "tie_word_embeddings": head_is_tied(cfg),
               "hidden_act": "silu" if cfg.mlp_kind == "swiglu" else "gelu",
               "attention_bias": cfg.qkv_bias,
               "activation_dtype": cfg.act_dtype_name}
@@ -155,13 +213,19 @@ def program_config(config: dict):
 
 
 def reference_sizes(cfg, config: dict, traffic: dict) -> dict:
+    """What the reference and the FLOP count are given: the dense sizes
+    as the program runs them, the reference's blocking, ``arch`` (every
+    architecture key the file states) and ``published`` (the published
+    value of each key in ``reduced``)."""
     ref = traffic["reference"]
     return {"d_model": cfg.d_model, "n_heads": cfg.n_heads, "n_kv": cfg.n_kv,
             "head_dim": cfg.head_dim_, "d_ff": cfg.d_ff, "vocab": cfg.vocab,
             "n_layers": cfg.n_layers, "rope_theta": float(cfg.rope_theta),
             "rms_norm_eps": float(config["rms_norm_eps"]),
             "z_loss": float(traffic["optimizer"]["z_loss"]),
-            "query_block": ref["query_block"], "head_chunk": ref["head_chunk"]}
+            "query_block": ref["query_block"], "head_chunk": ref["head_chunk"],
+            "arch": {k: v for k, v in config.items() if k not in META},
+            "published": config["published"]}
 
 
 def jit_step(step_fn, pshard, oshard):
@@ -208,13 +272,8 @@ def build_program(cell: Cell) -> Program:
     sizes = reference_sizes(cfg, cell.config, t)
     key = jax.ShapeDtypeStruct((2,), np.uint32)   # a raw PRNG key's shape
     with jax.set_mesh(mesh):
-        box = {}
-
-        def init(key):
-            params, box["axes"] = api.init(key)
-            return params
-        shapes = jax.eval_shape(init, key)
-        pshard, oshard = shd.train_state_shardings(box["axes"], shapes, mesh)
+        shapes, axes = param_shapes(api)
+        pshard, oshard = shd.train_state_shardings(axes, shapes, mesh)
         mine = jax.eval_shape(partial(cell.reference.make_weights, sizes),
                               key)
         if jax.tree.structure(mine) != jax.tree.structure(shapes) or any(
@@ -269,15 +328,23 @@ def drive(jstep, state, pool, first, *, count=None, deadline=None):
     return (params, opt_state), losses, metrics, i
 
 
+def leaf_norms(cell: Cell):
+    """``correctness.slice_norms`` with the reference's expert leaves."""
+    from correctness import slice_norms
+    return partial(slice_norms,
+                   experts=getattr(cell.reference, "EXPERT_LEAVES", ()))
+
+
 def first_steps(prog: Program, cell: Cell, pool, key):
     """Set-up: weights from the seed, then the first steps through
     :func:`drive`, recording what the comparison needs.  Returns
     ``(state, record, next index)``; the state goes on to the window."""
     import jax
     import jax.numpy as jnp
-    from correctness import flatten, slice_norms
+    from correctness import flatten
     o = cell.traffic["optimizer"]
     n = cell.traffic["reference"]["steps"]
+    norms = leaf_norms(cell)
     with jax.set_mesh(prog.mesh):
         params = prog.weights(key)
         state = (params, prog.opt_init(params))
@@ -286,12 +353,12 @@ def first_steps(prog: Program, cell: Cell, pool, key):
         # clipped gradient; undo both to get the gradient as it came in
         gn = float(m1["grad_norm"])
         clip = min(1.0, o["clip_norm"] / (gn + 1e-9))
-        mu = jax.device_get(jax.jit(slice_norms)(state[1].mu))
+        mu = jax.device_get(jax.jit(norms)(state[1].mu))
         grad = {k: v / ((1.0 - o["b1"]) * clip)
                 for k, v in flatten(mu).items()}
         state, more, _, i = drive(prog.jstep, state, pool, i, count=n - 1)
         losses += more
-        change = jax.jit(lambda p, k: slice_norms(
+        change = jax.jit(lambda p, k: norms(
             jax.tree.map(jnp.subtract, p, prog.weights(k))))
         update = flatten(jax.device_get(change(state[0], key)))
     return state, {"losses": losses, "grad": grad, "update": update}, i
@@ -301,12 +368,12 @@ def reference_record(cell: Cell, prog: Program, pool, key, *,
                      precision="f32", rows=None):
     """The reference (or its control, or a fault put in its place) over
     the batches of the first steps."""
-    from correctness import flatten, slice_norms
+    from correctness import flatten
     r = cell.traffic["reference"]
     losses, grad, update = cell.reference.train(
         prog.sizes, cell.traffic["optimizer"], key, list(pool[:r["steps"]]),
         precision=precision, rows=rows, rows_per_block=r["rows_per_block"],
-        norms=slice_norms)
+        norms=leaf_norms(cell))
     return {"losses": losses, "grad": flatten(grad),
             "update": flatten(update)}
 

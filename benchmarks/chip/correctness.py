@@ -14,7 +14,8 @@ Three numbers, each against a limit of the cell's own
 A cell's limits file names the numbers it compares.
 
 A leaf is one published parameter tensor: one layer's slice of a
-stacked array, or an unstacked array.  A leaf's gap is
+stacked array, one (layer, expert) slice of a stacked array that the
+reference names as an expert leaf, or an unstacked array.  A leaf's gap is
 ``|program - reference|`` over the larger of the reference's norm of
 that leaf and of the median leaf.  Leaves whose reference gradient is
 under a thousandth of the median leaf's move by rounding alone and are
@@ -30,15 +31,18 @@ NUMBERS = ("loss_gap", "grad_gap", "update_gap")
 TINY_GRAD = 1e-3
 
 
-def slice_norms(tree) -> dict:
+def slice_norms(tree, experts=()) -> dict:
     """Per-leaf L2 norms (jittable): ``{path: norm}`` for unstacked
-    arrays and ``{path: (layers,) norms}`` for the stacked ones under
-    ``layers``."""
+    arrays, ``{path: (layers,) norms}`` for the stacked ones under
+    ``layers``, and ``{path: (layers, experts) norms}`` for the stacked
+    ones named in ``experts``, whose second axis is the expert."""
     out = {}
     for path, leaf in jax.tree_util.tree_leaves_with_path(tree):
         name = ".".join(str(getattr(k, "key", k)) for k in path)
         x = leaf.astype(jnp.float32)
-        if name.startswith("layers."):
+        if name in experts:
+            out[name] = jnp.sqrt(jnp.sum(x * x, axis=tuple(range(2, x.ndim))))
+        elif name.startswith("layers."):
             out[name] = jnp.sqrt(jnp.sum(x * x, axis=tuple(range(1, x.ndim))))
         else:
             out[name] = jnp.sqrt(jnp.sum(x * x))
@@ -46,15 +50,14 @@ def slice_norms(tree) -> dict:
 
 
 def flatten(norms: dict) -> dict:
-    """``{leaf: float}``, with a stacked array's layers as ``name[i]``."""
+    """``{leaf: float}``, with a stacked array's layers as ``name[i]``
+    and an expert leaf's (layer, expert) slices as ``name[i,e]``."""
     out = {}
     for name, v in norms.items():
         v = np.asarray(v, np.float64)
-        if v.ndim:
-            for i, x in enumerate(v):
-                out[f"{name}[{i}]"] = float(x)
-        else:
-            out[name] = float(v)
+        for idx in np.ndindex(v.shape):
+            out[f"{name}[{','.join(map(str, idx))}]" if idx else name] = \
+                float(v[idx])
     return out
 
 

@@ -1,5 +1,6 @@
 """The harness finds every file that BENCHMARK.json names, by name, and
 BENCHMARK.json keeps to the shape the benchmark's check reads."""
+import dataclasses
 import json
 import re
 
@@ -62,6 +63,12 @@ def test_configuration_file(conf):
     cfg = chip_harness.program_config(data)
     assert cfg.vocab == data["vocab_size"]
     assert cfg.n_layers == data["num_hidden_layers"]
+    # every mapped key names a field of the program, and every cut of
+    # scale reaches the program
+    fields = {**chip_harness.FIELDS, **data.get("program_fields", {})}
+    assert set(fields.values()) <= {f.name for f in dataclasses.fields(cfg)}
+    for key in data["why_reduced"]:
+        assert chip_harness.field_value(cfg, fields[key]) == data[key]
 
 
 @pytest.mark.parametrize("name", CELLS)
